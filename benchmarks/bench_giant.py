@@ -8,8 +8,10 @@ its OWN subprocess (per-arm ``ru_maxrss``, like bench_stream):
 
   * ``dense``    single-device ADMM oracle on the giant block (the eigh
                  path every PR-2 route bottoms out in);
-  * ``sharded``  8 emulated devices (``xla_force_host_platform_device_
-                 count``), the full engine path with an oversize threshold
+  * ``sharded``  the local devices — 8 emulated ones
+                 (``xla_force_host_platform_device_count``) when
+                 ``JAX_PLATFORMS=cpu`` was asked for, the chips otherwise —
+                 through the full engine path with an oversize threshold
                  below the giant block: screen -> oversize class ->
                  shard-direct gather -> mesh-spanning no-eigh ADMM ->
                  distributed KKT verification.
@@ -118,10 +120,11 @@ def run_arm(arm: str, p: int, seed: int = 0) -> dict:
         from repro.core.glasso import glasso
         from repro.core.instrument import counts
 
-        assert jax.device_count() == DEVICES, (
-            f"sharded arm expected {DEVICES} emulated devices, got "
-            f"{jax.device_count()} — spawn via the parent"
-        )
+        if _emulated():
+            assert jax.device_count() == DEVICES, (
+                f"sharded arm expected {DEVICES} emulated devices, got "
+                f"{jax.device_count()} — spawn via the parent"
+            )
         res = glasso(
             S, LAM,
             options=EngineOptions(
@@ -143,6 +146,7 @@ def run_arm(arm: str, p: int, seed: int = 0) -> dict:
             "theta_trace": float(np.trace(comp_theta)),
             "theta_absum": float(np.abs(comp_theta).sum()),
             "theta_file": _dump_theta(comp_theta),
+            "devices": jax.device_count(),
         }
     else:
         raise ValueError(arm)
@@ -173,14 +177,19 @@ def _dump_theta(theta: np.ndarray) -> str:
     return path
 
 
+def _emulated() -> bool:
+    """The mesh is emulated only where the CPU platform was asked for
+    explicitly; on an accelerator host the sharded arm uses its chips."""
+    return os.environ.get("JAX_PLATFORMS") == "cpu"
+
+
 def _spawn_arm(arm: str, p: int) -> dict:
     env = dict(os.environ)
-    if arm == "sharded":
+    if arm == "sharded" and _emulated():
         env["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={DEVICES} "
             + env.get("XLA_FLAGS", "")
         ).strip()
-        env.setdefault("JAX_PLATFORMS", "cpu")
     out = subprocess.run(
         [sys.executable, "-m", "benchmarks.bench_giant", "--arm", arm,
          "--p", str(p)],
@@ -210,7 +219,7 @@ def run(p: int = P, log=print) -> dict:
     rec = {
         "p": p,
         "b_giant": dense["b_giant"],
-        "devices": DEVICES,
+        "devices": sharded["devices"],
         "lam": LAM,
         "max_diff": max_diff,
         "tol_scaled": TOL * scale,
@@ -232,7 +241,7 @@ def run(p: int = P, log=print) -> dict:
         f"p={p} giant b={rec['b_giant']}: dense {dense['seconds']}s "
         f"({dense['iters']} eigh iters, {dense['device_bytes']/2**20:.1f}MB "
         f"on 1 device)  vs  sharded {sharded['seconds']}s "
-        f"({sharded['inner_iters']} inner iters across {DEVICES} devices, "
+        f"({sharded['inner_iters']} inner iters across {sharded['devices']} devices, "
         f"{sharded['device_bytes']/2**20:.1f}MB/device, ratio "
         f"{rec['device_bytes_ratio']}); max|dTheta|={max_diff:.2e} "
         f"(accept {rec['tol_scaled']:.2e}), fallbacks={rec['fallbacks']}"

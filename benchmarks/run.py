@@ -124,6 +124,10 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="CI equivalence gate (engine path == dense path)")
     args = ap.parse_args()
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
+    from repro.launch.compile_cache import use_checkout_cache
+
+    use_checkout_cache()
 
     if args.smoke:
         smoke()

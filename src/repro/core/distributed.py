@@ -30,6 +30,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.jax_compat import shard_map
 
+#: f32 products at full precision on the TPU (its default is one bf16 pass)
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def distributed_components(
     S: jax.Array, lam, mesh, *, axis: str = "data", max_rounds: int | None = None
@@ -148,7 +151,7 @@ def ring_matmul(a_rows: jax.Array, b_rows: jax.Array, *, axis: str, n_shards: in
     gathered product, but the only extra buffer is one (rl, p) shard instead
     of the full (p, p) all-gather."""
     if n_shards == 1:
-        return a_rows @ b_rows
+        return jnp.matmul(a_rows, b_rows, precision=_HIGHEST)
     rl = a_rows.shape[0]
     idx = jax.lax.axis_index(axis)
     perm = [(j, (j - 1) % n_shards) for j in range(n_shards)]
@@ -158,7 +161,7 @@ def ring_matmul(a_rows: jax.Array, b_rows: jax.Array, *, axis: str, n_shards: in
         s = jax.lax.rem((idx + k).astype(jnp.int32), jnp.int32(n_shards))
         col0 = (s * rl).astype(jnp.int32)
         a_cols = jax.lax.dynamic_slice(a_rows, (jnp.int32(0), col0), (rl, rl))
-        acc = acc + a_cols @ b_cur
+        acc = acc + jnp.matmul(a_cols, b_cur, precision=_HIGHEST)
         b_cur = jax.lax.ppermute(b_cur, axis, perm)
         return acc, b_cur
 
@@ -185,8 +188,10 @@ def transpose_rowsharded(a_rows: jax.Array, *, axis: str, n_shards: int):
 def matvec_rowsharded(a_rows: jax.Array, v: jax.Array, *, axis: str, n_shards: int):
     """(A @ v) replicated, from A row-sharded and v replicated."""
     if n_shards == 1:
-        return a_rows @ v
-    return jax.lax.all_gather(a_rows @ v, axis, tiled=True)
+        return jnp.matmul(a_rows, v, precision=_HIGHEST)
+    return jax.lax.all_gather(
+        jnp.matmul(a_rows, v, precision=_HIGHEST), axis, tiled=True
+    )
 
 
 def device_memory_budget_mb() -> float | None:

@@ -1,12 +1,8 @@
-"""Version-compat shims for the handful of jax APIs that moved after 0.4.x.
+"""Mesh and ``shard_map`` helpers shared by the distributed screening and
+solving backends.
 
-The container pins jax 0.4.37 while some call sites were written against the
-newer surface; everything engine-side goes through these helpers so the
-distributed screening/solving backends stay first-class on either version:
-
-    shard_map(...)   jax.shard_map (>=0.6, ``check_vma``) vs
-                     jax.experimental.shard_map.shard_map (0.4.x, ``check_rep``)
-    make_mesh(...)   ``axis_types`` keyword only exists on newer jax
+    shard_map(...)   ``jax.shard_map`` with the replication check off
+    make_mesh(...)   ``jax.make_mesh`` with Auto axis types
 """
 
 from __future__ import annotations
@@ -15,26 +11,20 @@ import jax
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """Unreplicated-output-check disabled in both dialects (the label-prop
-    while_loop trips the 0.4.x replication checker)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
+    """``jax.shard_map`` with ``check_vma=False`` (the label-prop
+    while_loop's outputs are not provably replicated)."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )
 
 
-def make_mesh(axis_shapes, axis_names, *, auto_axes: bool = True):
-    """jax.make_mesh with axis_types=Auto where supported."""
-    if auto_axes and hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            axis_shapes,
-            axis_names,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
-        )
-    return jax.make_mesh(axis_shapes, axis_names)
+def make_mesh(axis_shapes, axis_names):
+    """``jax.make_mesh`` with every axis of type Auto."""
+    return jax.make_mesh(
+        axis_shapes,
+        axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
+    )
 
 
 _LOCAL_MESHES: dict[tuple, object] = {}

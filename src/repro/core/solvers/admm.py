@@ -48,6 +48,16 @@ import jax
 import jax.numpy as jnp
 
 
+def theta_eigenvalues(d, rho):
+    """Roots theta > 0 of rho theta - 1 / theta = d, the Theta-update's
+    eigenvalues.  The textbook (d + sqrt(d^2 + 4 rho)) / (2 rho) cancels
+    for d << 0 (in float32 it loses every digit once |d| ~ 1e3 sqrt(rho)
+    and stalls ADMM far from the optimum); there the equal form
+    2 / (sqrt(d^2 + 4 rho) - d) is exact to rounding."""
+    r = jnp.sqrt(d * d + 4.0 * rho)
+    return jnp.where(d >= 0, (d + r) / (2.0 * rho), 2.0 / (r - d))
+
+
 def _soft(x, t):
     return jnp.sign(x) * jnp.maximum(jnp.abs(x) - t, 0.0)
 
@@ -73,8 +83,10 @@ def glasso_admm_info(
     def theta_update(Z, U, rho):
         rhs = rho * (Z - U) - S
         d, Q = jnp.linalg.eigh(rhs)
-        theta_d = (d + jnp.sqrt(d * d + 4.0 * rho)) / (2.0 * rho)
-        return (Q * theta_d[None, :]) @ Q.T
+        theta_d = theta_eigenvalues(d, rho)
+        return jnp.matmul(
+            Q * theta_d[None, :], Q.T, precision=jax.lax.Precision.HIGHEST
+        )
 
     def body(carry):
         Z, U, rho, _, _, it = carry

@@ -18,10 +18,12 @@ paper observes this check is an immediate consequence of the block updates yet
 was *missing* from GLASSO 1.4 — we make it explicit: the inner CD loop is
 skipped entirely for screened columns (a lax.cond on the hot path).
 
-Everything is expressed with masked full-matrix ops (no row/col deletion), so
-the solver jits once per block size and vmaps across a bucket of same-size
-components — that batching is what feeds the MXU well on TPU (DESIGN.md
-Section 3).
+Everything is expressed with full-matrix ops on 2-D rows and columns (no
+row/col deletion, no matrix products), so the solver jits once per block
+size, vmaps across a bucket of same-size components, and the same sweep code
+runs inside the ``bucket_glasso`` Pallas kernel (``bcd_sweeps``).  The
+products are elementwise multiplies with reductions, which keep full f32
+accuracy on the TPU, where an f32 matmul at default precision would not.
 """
 
 from __future__ import annotations
@@ -36,42 +38,166 @@ def _soft(x, t):
     return jnp.sign(x) * jnp.maximum(jnp.abs(x) - t, 0.0)
 
 
-def _lasso_cd(W, s12, lam, beta0, j, *, n_cd: int, tol) -> jax.Array:
-    """Cyclic coordinate descent for (9) on column j.
+# -- exact data movement ------------------------------------------------------
+#
+# The sweep arithmetic below is written once, on 2-D values: rows (1, b),
+# columns (b, 1) and (1, 1) scalars.  Reading or writing one row, column or
+# entry at a traced index is pure data movement and has two exact spellings:
+# dynamic slices (``masked=False``: O(b) per access, what XLA runs) and iota
+# masks with a one-hot reduction (``masked=True``: what a Pallas TPU kernel
+# can lower, since Mosaic has no dynamic_slice on values).  Both move the same
+# bits (a one-hot sum adds only zeros; the sign of a zero is the one thing it
+# may drop), so ``glasso_bcd``, the fused reference and the bucket_glasso
+# kernel agree lane for lane under ``==``.
 
-    beta is a length-b vector with beta[j] pinned to 0.  Coordinate update:
+
+def _take(M, k, axis: int, masked: bool):
+    """Row (axis=0, -> (1, b)) or column (axis=1, -> (b, 1)) k of M."""
+    if not masked:
+        return jax.lax.dynamic_slice_in_dim(M, k, 1, axis=axis)
+    idx = jax.lax.broadcasted_iota(jnp.int32, M.shape, axis)
+    return jnp.sum(jnp.where(idx == k, M, 0.0), axis=axis, keepdims=True)
+
+
+def _put(M, k, v, axis: int, masked: bool):
+    """M with row (axis=0) or column (axis=1) k replaced by v."""
+    if not masked:
+        return jax.lax.dynamic_update_slice_in_dim(M, v, k, axis=axis)
+    idx = jax.lax.broadcasted_iota(jnp.int32, M.shape, axis)
+    return jnp.where(idx == k, v, M)
+
+
+def _flip(v, masked: bool):
+    """(1, b) <-> (b, 1) transpose of a vector."""
+    if not masked:
+        return v.reshape(v.shape[::-1])
+    n = max(v.shape)
+    eye = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0) == (
+        jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    )
+    axis = 1 if v.shape[0] == 1 else 0
+    return jnp.sum(jnp.where(eye, v, 0.0), axis=axis, keepdims=True)
+
+
+def _lasso_cd(W, s12, lam, beta0, j, *, n_cd: int, tol, masked: bool):
+    """Cyclic coordinate descent for (9) on column j; vectors are (1, b) rows.
+
+    beta[j] is pinned to 0.  Coordinate update:
         beta_k <- soft(s12_k - sum_{l != k} W_kl beta_l, lam) / W_kk
     Runs until the sweep-wise max update < tol or n_cd sweeps.
     """
     b = W.shape[0]
-    kk = jnp.arange(b)
+    zero = jnp.zeros((1, 1), W.dtype)
 
     def sweep(beta):
         def coord(k, carry):
             beta, delta = carry
-            r = s12[k] - (W[k, :] @ beta - W[k, k] * beta[k])
-            new = _soft(r, lam) / W[k, k]
-            new = jnp.where(k == j, 0.0, new)
-            delta = jnp.maximum(delta, jnp.abs(new - beta[k]))
-            return beta.at[k].set(new), delta
+            wk = _take(W, k, 0, masked)
+            wkk = _take(wk, k, 1, masked)
+            bk = _take(beta, k, 1, masked)
+            dot = jnp.sum(wk * beta, axis=1, keepdims=True)
+            r = _take(s12, k, 1, masked) - (dot - wkk * bk)
+            new = _soft(r, lam) / wkk
+            new = jnp.where(k == j, zero, new)
+            delta = jnp.maximum(delta, jnp.abs(new - bk))
+            return _put(beta, k, new, 1, masked), delta
 
-        beta, delta = jax.lax.fori_loop(0, b, coord, (beta, jnp.zeros((), W.dtype)))
-        return beta, delta
+        return jax.lax.fori_loop(0, b, coord, (beta, zero))
 
     def cond(c):
         _, delta, it = c
-        return jnp.logical_and(delta > tol, it < n_cd)
+        return jnp.logical_and(jnp.max(delta) > tol, it < n_cd)
 
     def body(c):
         beta, _, it = c
         beta, delta = sweep(beta)
         return beta, delta, it + 1
 
-    beta0 = beta0.at[j].set(0.0)
-    beta, delta = sweep(beta0)
+    beta, delta = sweep(_put(beta0, j, zero, 1, masked))
     beta, _, _ = jax.lax.while_loop(cond, body, (beta, delta, jnp.int32(1)))
-    del kk
     return beta
+
+
+def bcd_sweeps(
+    ST: jax.Array,
+    W: jax.Array,
+    BT: jax.Array,
+    lam,
+    thr,
+    *,
+    max_sweeps: int,
+    n_cd: int,
+    node_screen: bool,
+    masked: bool = False,
+) -> tuple[jax.Array, jax.Array]:
+    """The BCD sweep loop from an initialized state; returns (Theta^T, sweeps).
+
+    ``ST`` is S transposed (row j is column j of S), ``W`` the initial
+    covariance iterate with its diagonal already at S_ii + lam, ``BT`` the
+    initial lasso coefficients transposed (row j is beta for column j), and
+    ``thr`` = tol * scale, both the sweep and the inner CD tolerance.  The
+    caller symmetrizes: Theta = (Theta^T + Theta^T') / 2.
+    """
+    b = W.shape[0]
+    dtype = W.dtype
+    zero = jnp.zeros((1, 1), dtype)
+    eye = jax.lax.broadcasted_iota(jnp.int32, (b, b), 0) == (
+        jax.lax.broadcasted_iota(jnp.int32, (b, b), 1)
+    )
+
+    def column_update(j, W, BT):
+        s12 = _put(_take(ST, j, 0, masked), j, zero, 1, masked)
+        screened = jnp.max(jnp.abs(s12)) <= lam
+
+        def solve_col(operand):
+            W, beta0 = operand
+            return _lasso_cd(
+                W, s12, lam, beta0, j, n_cd=n_cd, tol=thr, masked=masked
+            )
+
+        def zero_col(operand):
+            _, beta0 = operand
+            return jnp.zeros_like(beta0)
+
+        beta0 = _take(BT, j, 0, masked)
+        if node_screen:
+            beta = jax.lax.cond(screened, zero_col, solve_col, (W, beta0))
+        else:
+            beta = solve_col((W, beta0))
+        w12 = jnp.sum(W * beta, axis=1, keepdims=True)  # W @ beta, (b, 1)
+        wjj = _take(_take(W, j, 0, masked), j, 1, masked)
+        w12 = _put(w12, j, wjj, 0, masked)
+        W = _put(W, j, w12, 1, masked)
+        W = _put(W, j, _flip(w12, masked), 0, masked)
+        return W, _put(BT, j, beta, 0, masked)
+
+    def sweep(carry):
+        W, BT, _, it = carry
+        W_old = W
+        W, BT = jax.lax.fori_loop(
+            0, b, lambda j, wb: column_update(j, *wb), (W, BT)
+        )
+        delta = jnp.max(jnp.abs(W - W_old))
+        return W, BT, delta, it + 1
+
+    def cond(carry):
+        _, _, delta, it = carry
+        return jnp.logical_and(delta > thr, it < max_sweeps)
+
+    W, BT, delta, _ = sweep(
+        (W, BT, jnp.asarray(jnp.inf, dtype), jnp.int32(0))
+    )
+    W, BT, _, sweeps = jax.lax.while_loop(
+        cond, sweep, (W, BT, delta, jnp.int32(1))
+    )
+
+    # Recover Theta column-wise from the final (W, B): W is exactly
+    # symmetric after a full sweep, so w12 of column j is row j of W.
+    #   theta_22 = 1 / (w22 - w12' beta),   theta_12 = -beta * theta_22
+    dots = jnp.sum(jnp.where(eye, 0.0, W) * BT, axis=1, keepdims=True)
+    wdiag = jnp.sum(jnp.where(eye, W, 0.0), axis=1, keepdims=True)
+    t22 = 1.0 / (wdiag - dots)
+    return jnp.where(eye, t22, -BT * t22), sweeps
 
 
 @functools.partial(
@@ -111,57 +237,9 @@ def glasso_bcd(
         d = jnp.where(d > 0, d, jnp.ones((), dtype))  # PD => d > 0; belt+braces
         B_init = jnp.where(jnp.eye(b, dtype=bool), 0.0, -(Theta0 / d[None, :]))
     scale = jnp.mean(jnp.abs(S - jnp.diag(jnp.diag(S)))) + jnp.asarray(1e-12, dtype)
-
-    cd_tol = jnp.asarray(tol, dtype) * scale
-
-    def column_update(j, W, B):
-        s12 = S[:, j].at[j].set(0.0)
-        screened = jnp.max(jnp.abs(s12)) <= lam
-
-        def solve_col(operand):
-            W, beta0 = operand
-            beta = _lasso_cd(W, s12, lam, beta0, j, n_cd=n_cd, tol=cd_tol)
-            return beta
-
-        def zero_col(operand):
-            _, beta0 = operand
-            return jnp.zeros_like(beta0)
-
-        if node_screen:
-            beta = jax.lax.cond(screened, zero_col, solve_col, (W, B[:, j]))
-        else:
-            beta = solve_col((W, B[:, j]))
-        w12 = (W @ beta).at[j].set(0.0)
-        W = W.at[:, j].set(w12.at[j].set(W[j, j]))
-        W = W.at[j, :].set(w12.at[j].set(W[j, j]))
-        return W, B.at[:, j].set(beta)
-
-    def sweep(carry):
-        W, B, _, it = carry
-        W_old = W
-
-        def body(j, wb):
-            W, B = wb
-            return column_update(j, W, B)
-
-        W, B = jax.lax.fori_loop(0, b, body, (W, B))
-        delta = jnp.max(jnp.abs(W - W_old))
-        return W, B, delta, it + 1
-
-    def cond(carry):
-        _, _, delta, it = carry
-        return jnp.logical_and(delta > tol * scale, it < max_sweeps)
-
-    W, B, delta, _ = sweep((W_init, B_init, jnp.asarray(jnp.inf, dtype), jnp.int32(0)))
-    W, B, _, _ = jax.lax.while_loop(cond, sweep, (W, B, delta, jnp.int32(1)))
-
-    # Recover Theta column-wise from the final (W, B).
-    def theta_col(j):
-        beta = B[:, j]
-        w12 = W[:, j].at[j].set(0.0)
-        t22 = 1.0 / (W[j, j] - w12 @ beta)
-        col = -beta * t22
-        return col.at[j].set(t22)
-
-    Theta = jax.vmap(theta_col, out_axes=1)(jnp.arange(b))
-    return 0.5 * (Theta + Theta.T)
+    thr = jnp.asarray(tol, dtype) * scale
+    ThetaT, _ = bcd_sweeps(
+        S.T, W_init, B_init.T, lam, thr,
+        max_sweeps=max_sweeps, n_cd=n_cd, node_screen=node_screen,
+    )
+    return 0.5 * (ThetaT.T + ThetaT)

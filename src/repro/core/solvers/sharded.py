@@ -107,6 +107,9 @@ class ShardedSolve:
     iters: int                 # outer ADMM iterations
     inner_iters: int           # Newton-Schulz + verification-CG steps
     retries: int               # outer steps reverted by the NS safeguard
+    stalls: int                # ADMM loops ended by the float32 stall stop
+    admm_residual: float       # max(primal, dual) residual where ADMM stopped
+    admm_eps: float            # the eps that last loop was running to
     kkt_residual: float        # distributed eq.-(11)/(12) residual of Theta
     s_max: float               # max |S| over the padded block (KKT scale)
     rho: float                 # final (adapted) ADMM penalty
@@ -160,7 +163,10 @@ def _build_sharded(
 
             v = jax.lax.fori_loop(0, pow_steps, body, v)
             u = mv(A_rows, v)
-            return jnp.abs(v @ u), u / (jnp.linalg.norm(u) + 1e-30)
+            return (
+                jnp.abs(jnp.dot(v, u, precision=jax.lax.Precision.HIGHEST)),
+                u / (jnp.linalg.norm(u) + 1e-30),
+            )
 
         def sqrt_ns(A_rows, c, ns_tol):
             """sqrt(A) via the coupled Newton-Schulz iteration on A / c.
@@ -221,6 +227,18 @@ def _build_sharded(
 
         kkt_rel = scalars[3]  # relative KKT target (inf = single attempt)
         diag_own = jnp.sum(jnp.where(eye_loc, S_rows, 0.0), axis=1)
+        # Rounding floors.  Newton-Schulz stalls a few ulps (growing like
+        # sqrt(bp)) from I, and the ADMM residuals stall at rounding noise
+        # well above the eps a float64 tolerance asks for.  A tolerance under
+        # its floor never passes: Newton-Schulz would run to ns_max with
+        # every outer step reverted, ADMM to max_iter.  So the Newton-Schulz
+        # tolerance is floored at the unit roundoff's scale, and in float32
+        # the ADMM loop also stops once its residuals have stopped falling
+        # (no 1% gain in ``stall`` steps).  Both bind only in float32; the
+        # stall stops are counted and the residual they stopped at returned.
+        unit = float(jnp.finfo(dtype).eps)
+        ns_floor = max(1e-11, 4.0 * unit * bp**0.5)
+        stall = max_iter if unit < 1e-10 else 50
         if warm:
             # At the ADMM fixed point U* = (Theta*^{-1} - S) / rho (the
             # Theta-update optimality rho Theta - Theta^{-1} = rho (Z - U) - S
@@ -243,15 +261,20 @@ def _build_sharded(
         v0 = jnp.ones((bp,), S_rows.dtype) / jnp.sqrt(jnp.asarray(bp, S_rows.dtype))
 
         def admm_cond(c):
-            _, _, _, _, _, rp, rd, it, _, retries, eps = c
-            return ((rp > eps) | (rd > eps)) & (it < max_iter) & (retries < 30)
+            _, _, _, _, _, rp, rd, it, _, retries, eps, _, since = c
+            return (
+                ((rp > eps) | (rd > eps))
+                & (it < max_iter)
+                & (retries < 30)
+                & (since < stall)
+            )
 
         def admm_body(c):
-            Z, U, v, rho, boost, rp, rd, it, inner, retries, eps = c
+            Z, U, v, rho, boost, rp, rd, it, inner, retries, eps, best, since = c
             M = rho * (Z - U) - S_rows
             m, vn = power_norm(M, v)
             cscale = boost * (m * m + 4.0 * rho)
-            ns_tol = jnp.clip(1e-3 * rp / bp, 1e-11, 1e-2)
+            ns_tol = jnp.clip(1e-3 * rp / bp, ns_floor, 1e-2)
             A = mm(M, M) + 4.0 * rho * eyef
             R_sqrt, ns_k, ns_ok = sqrt_ns(A, cscale, ns_tol)
             Theta = (M + R_sqrt) / (2.0 * rho)
@@ -268,6 +291,8 @@ def _build_sharded(
                 ),
             )
             ok = ns_ok & jnp.isfinite(rp_n) & jnp.isfinite(rd_n)
+            r = jnp.maximum(rp_n, rd_n)
+            gained = ok & (r < 0.99 * best)
             return (
                 jnp.where(ok, Zn, Z),
                 jnp.where(ok, Un / factor, U),
@@ -280,6 +305,8 @@ def _build_sharded(
                 inner + ns_k,
                 retries + jnp.where(ok, 0, 1).astype(jnp.int32),
                 eps,
+                jnp.where(gained, r, best),
+                jnp.where(gained, 0, since + 1),
             )
 
         def kkt_of(Zf, W_warm, inner_tol):
@@ -311,7 +338,7 @@ def _build_sharded(
         # eps makes the acceptance self-fulfilling within the max_iter
         # budget instead of a post-hoc coin flip.
         def attempt_cond(c):
-            st, _, res, _, att = c
+            st, _, res, _, att, _ = c
             it, retries = st[7], st[9]
             # att == 0 forces the first attempt even with no KKT target
             # (kkt_target = inf, where `res > inf` is already False)
@@ -323,17 +350,23 @@ def _build_sharded(
             )
 
         def attempt_body(c):
-            st, W_warm, _, eps, att = c
+            st, W_warm, _, eps, att, stalls = c
             st = jax.lax.while_loop(
-                admm_cond, admm_body, st[:10] + (eps,)
+                admm_cond,
+                admm_body,
+                st[:10] + (eps, jnp.asarray(jnp.inf, S_rows.dtype), jnp.int32(0)),
             )
-            (Z, U, v, rho, boost, rp, rd, it, inner, retries, _) = st
+            (Z, U, v, rho, boost, rp, rd, it, inner, retries) = st[:10]
+            stalled = (st[12] >= stall) & ((rp > eps) | (rd > eps))
             Zf = 0.5 * (Z + tr(Z))
             res, Wz, cg_k = kkt_of(Zf, W_warm, jnp.minimum(1e-8, tol))
             st_out = (
                 Zf, U, v, rho, boost, rp, rd, it, inner + cg_k, retries,
             )
-            return st_out + (eps,), Wz, res, 0.05 * eps, att + 1
+            return (
+                st_out + (eps,), Wz, res, 0.05 * eps, att + 1,
+                stalls + stalled.astype(jnp.int32),
+            )
 
         W_init = jnp.where(eye_loc, (1.0 / (diag_own + lam))[:, None], 0.0)
         init_state = (
@@ -349,13 +382,13 @@ def _build_sharded(
             jnp.int32(0),
             tol * bp,
         )
-        (st, _, res, _, _) = jax.lax.while_loop(
+        (st, _, res, _, _, stalls) = jax.lax.while_loop(
             attempt_cond,
             attempt_body,
             (init_state, W_init, jnp.asarray(jnp.inf, S_rows.dtype), tol * bp,
-             jnp.int32(0)),
+             jnp.int32(0), jnp.int32(0)),
         )
-        Zf, _, _, rho, _, _, _, it, inner, retries, _ = st
+        Zf, _, _, rho, _, rp, rd, it, inner, retries, eps = st
         stats = jnp.stack(
             [
                 it.astype(S_rows.dtype),
@@ -364,6 +397,9 @@ def _build_sharded(
                 s_max,
                 rho,
                 retries.astype(S_rows.dtype),
+                stalls.astype(S_rows.dtype),
+                jnp.maximum(rp, rd),
+                eps,
             ]
         )
         return Zf, stats
@@ -499,6 +535,7 @@ def glasso_sharded(
     device_bytes = _BUFFERS_PER_DEVICE * (bp // d) * bp * itemsize
     bump("solver.oversize.dispatched")
     bump("solver.oversize.cg_iters", int(stats[1]))
+    bump("solver.oversize.stalls", int(stats[6]))
     set_peak("solver.oversize.device_bytes_peak", device_bytes)
     Theta = np.asarray(Z)[:b, :b]
     return ShardedSolve(
@@ -506,6 +543,9 @@ def glasso_sharded(
         iters=int(stats[0]),
         inner_iters=int(stats[1]),
         retries=int(stats[5]),
+        stalls=int(stats[6]),
+        admm_residual=float(stats[7]),
+        admm_eps=float(stats[8]),
         kkt_residual=float(stats[2]),
         s_max=float(stats[3]),
         rho=float(stats[4]),

@@ -62,7 +62,8 @@ class GlassoResult:
         route_mix: dict | None = None,  # structure class -> #blocks
         routed: bool = True,       # was the routing ladder enabled?
         # sharded-route accounting for THIS solve: {dispatched, inner_iters,
-        # fallbacks} (empty when no block took the oversize route); the
+        # stalls, fallbacks} (empty when no block took the oversize route,
+        # stalls = ADMM loops ended by the float32 stall stop); the
         # process-wide view is instrument counts("solver.oversize.")
         oversize: dict | None = None,
         assemble_seconds: float = 0.0,  # scatter/index-build slice

@@ -273,7 +273,7 @@ def solve_sharded_bucket(
     (the shared ``dispatch_repair``) — correct, but memory-bound, so it is
     counted loudly: ``solver.oversize.fallbacks`` + ``router.fallback.
     oversize``.  Returns (padded (n, size, size) Theta stack, info dict
-    {dispatched, inner_iters, fallbacks} for ``GlassoResult.oversize``).
+    {dispatched, inner_iters, stalls, fallbacks} for ``GlassoResult.oversize``).
 
     Shared by the engine executor and the serving batcher, like
     ``dispatch_repair`` — oversize admission behaves identically everywhere.
@@ -286,7 +286,7 @@ def solve_sharded_bucket(
     np_dtype = np.dtype(jnp.dtype(dtype).name)
     n = len(bucket.comps)
     out = np.zeros((n, bucket.size, bucket.size), dtype=np_dtype)
-    info = {"dispatched": 0, "inner_iters": 0, "fallbacks": 0}
+    info = {"dispatched": 0, "inner_iters": 0, "stalls": 0, "fallbacks": 0}
     failed: list[int] = []
     for i, comp in enumerate(bucket.comps):
         b = len(comp)
@@ -299,6 +299,7 @@ def solve_sharded_bucket(
         )
         info["dispatched"] += 1
         info["inner_iters"] += res.inner_iters
+        info["stalls"] += res.stalls
         padded = np.eye(bucket.size, dtype=np_dtype) / (1.0 + lam)
         padded[:b, :b] = res.Theta
         out[i] = padded
@@ -439,7 +440,7 @@ class BucketExecutor:
     _prev_solutions: dict = field(default_factory=dict)
     _prev_blocks: dict = field(default_factory=dict)
     # oversize accounting of the MOST RECENT solve_plan call (dispatched /
-    # inner_iters / fallbacks) — surfaced as GlassoResult.oversize
+    # inner_iters / stalls / fallbacks) — surfaced as GlassoResult.oversize
     last_oversize: dict = field(default_factory=dict)
     # assembly-stage seconds of the MOST RECENT solve_plan call — surfaced
     # as GlassoResult.assemble_seconds (process-wide: engine.assemble_us)
@@ -763,7 +764,7 @@ class BucketExecutor:
         # seeds Theta0 from its own previous padded solution (the dense
         # warm_W path would require inverting a giant block on the host —
         # exactly the allocation the route avoids).
-        totals = {"dispatched": 0, "inner_iters": 0, "fallbacks": 0}
+        totals = {"dispatched": 0, "inner_iters": 0, "stalls": 0, "fallbacks": 0}
         for p in sharded_pending:
             bucket = p.bucket
             prev = (

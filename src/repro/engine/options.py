@@ -50,7 +50,8 @@ class EngineOptions:
 
     ``solver=None`` means "the context default" — "bcd" for single-class
     engines, "joint_admm" for the joint engine; ``dtype=None`` resolves to
-    ``jnp.float64``.  ``solver_opts`` holds the free-form per-solver knobs
+    ``float32`` on a TPU (which has no float64 kernels or LU) and to
+    ``float64`` elsewhere, the CPU reference precision.  ``solver_opts`` holds the free-form per-solver knobs
     (``tol``, ``max_iter``, ``rho``, ...) that used to travel as ``**kwargs``.
     """
 
@@ -100,10 +101,18 @@ class EngineOptions:
         return self.solver if self.solver is not None else default
 
     def resolved_dtype(self):
-        if self.dtype is None:
-            import jax.numpy as jnp
+        import jax
+        import jax.numpy as jnp
 
-            return jnp.float64
+        on_tpu = jax.default_backend() == "tpu"
+        if self.dtype is None:
+            return jnp.float32 if on_tpu else jnp.float64
+        if on_tpu and jnp.dtype(self.dtype) == jnp.float64:
+            raise ValueError(
+                "dtype float64 is not supported on the TPU (Pallas kernels "
+                "and LU have no float64 path); use dtype=float32 or leave "
+                "dtype unset"
+            )
         return self.dtype
 
     def np_dtype(self):

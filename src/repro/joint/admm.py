@@ -38,6 +38,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.core.solvers.admm import theta_eigenvalues
 from repro.kernels.joint_prox.ops import joint_prox_step
 
 
@@ -64,8 +65,10 @@ def joint_admm_info(
     def theta_update(Z, U, rho):
         rhs = rho * (Z - U) - S
         d, Q = jnp.linalg.eigh(rhs)  # batched over the class axis
-        theta_d = (d + jnp.sqrt(d * d + 4.0 * rho)) / (2.0 * rho)
-        return jnp.einsum("kij,kj,klj->kil", Q, theta_d, Q)
+        theta_d = theta_eigenvalues(d, rho)
+        return jnp.einsum(
+            "kij,kj,klj->kil", Q, theta_d, Q, precision=jax.lax.Precision.HIGHEST
+        )
 
     def body(carry):
         Z, U, rho, _, _, it = carry

@@ -88,7 +88,7 @@ from repro.kernels.tree_glasso.ops import glasso_forest_stack
 def joint_effective_lambda(lam1, lam2, K: int, *, penalty: str):
     """Effective single-class lambda of an identical-block joint component."""
     if penalty == "group":
-        return lam1 + lam2 / np.sqrt(float(K))
+        return lam1 + lam2 / float(np.sqrt(K))  # a weak scalar keeps the dtype
     return lam1 + 0.0 * lam2
 
 

@@ -19,9 +19,10 @@ make it PACKABLE across bucket boundaries without changing any lane's bits:
   SOURCE shape (``engine.waves.bucket_scales``) and each lane solves against
   the tolerance its unfused dispatch would have used.
 
-Everything else — inner ``_lasso_cd``, column update, sweep loop, Theta
-recovery — is imported from / verbatim to ``bcd.py``; tests/test_fused.py
-pins the lane-for-lane ``==``-equality against per-bucket ``glasso_bcd``.
+Everything else — inner coordinate descent, column update, sweep loop, Theta
+recovery — is ``bcd.bcd_sweeps``, the code ``glasso_bcd`` runs;
+tests/test_fused.py pins the lane-for-lane ``==``-equality against
+per-bucket ``glasso_bcd``.
 
 The second return is the per-lane SWEEP COUNT: under ``vmap`` the while_loop
 is select-masked (converged lanes freeze, so packing cannot change results)
@@ -37,7 +38,31 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.core.solvers.bcd import _lasso_cd
+from repro.core.solvers.bcd import bcd_sweeps
+
+
+def fused_bcd_init(
+    S: jax.Array,
+    lam: jax.Array,
+    scale: jax.Array,
+    W0: jax.Array,
+    Theta0: jax.Array,
+    *,
+    tol: float,
+) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """One lane's sweep-loop state: (S^T, W_init, B_init^T, tol * scale).
+
+    Shared by the reference and the Pallas wrapper, which runs it in XLA
+    ahead of the kernel, so both start every lane from the same bits."""
+    b = S.shape[0]
+    dtype = S.dtype
+    lam = jnp.asarray(lam, dtype)
+    # Diagonal KKT is exact at the solution; enforce from the start.
+    W_init = jnp.where(jnp.eye(b, dtype=bool), jnp.diag(S) + lam, W0)
+    d = jnp.diagonal(Theta0)
+    d = jnp.where(d > 0, d, jnp.ones((), dtype))  # PD => d > 0; belt+braces
+    B_init = jnp.where(jnp.eye(b, dtype=bool), 0.0, -(Theta0 / d[None, :]))
+    return S.T, W_init, B_init.T, jnp.asarray(tol, dtype) * scale
 
 
 def fused_bcd_single(
@@ -59,67 +84,13 @@ def fused_bcd_single(
     eq.-(10)-screened exactly and the [:s, :s] slice of the result equals
     the unfused solve of the (s, s) block bit for bit (up to zero signs).
     """
-    b = S.shape[0]
-    dtype = S.dtype
-    lam = jnp.asarray(lam, dtype)
-    # Diagonal KKT is exact at the solution; enforce from the start.
-    W_init = jnp.where(jnp.eye(b, dtype=bool), jnp.diag(S) + lam, W0)
-    d = jnp.diagonal(Theta0)
-    d = jnp.where(d > 0, d, jnp.ones((), dtype))  # PD => d > 0; belt+braces
-    B_init = jnp.where(jnp.eye(b, dtype=bool), 0.0, -(Theta0 / d[None, :]))
-    cd_tol = jnp.asarray(tol, dtype) * scale
-
-    def column_update(j, W, B):
-        s12 = S[:, j].at[j].set(0.0)
-        screened = jnp.max(jnp.abs(s12)) <= lam
-
-        def solve_col(operand):
-            W, beta0 = operand
-            beta = _lasso_cd(W, s12, lam, beta0, j, n_cd=n_cd, tol=cd_tol)
-            return beta
-
-        def zero_col(operand):
-            _, beta0 = operand
-            return jnp.zeros_like(beta0)
-
-        if node_screen:
-            beta = jax.lax.cond(screened, zero_col, solve_col, (W, B[:, j]))
-        else:
-            beta = solve_col((W, B[:, j]))
-        w12 = (W @ beta).at[j].set(0.0)
-        W = W.at[:, j].set(w12.at[j].set(W[j, j]))
-        W = W.at[j, :].set(w12.at[j].set(W[j, j]))
-        return W, B.at[:, j].set(beta)
-
-    def sweep(carry):
-        W, B, _, it = carry
-        W_old = W
-
-        def body(j, wb):
-            W, B = wb
-            return column_update(j, W, B)
-
-        W, B = jax.lax.fori_loop(0, b, body, (W, B))
-        delta = jnp.max(jnp.abs(W - W_old))
-        return W, B, delta, it + 1
-
-    def cond(carry):
-        _, _, delta, it = carry
-        return jnp.logical_and(delta > tol * scale, it < max_sweeps)
-
-    W, B, delta, _ = sweep((W_init, B_init, jnp.asarray(jnp.inf, dtype), jnp.int32(0)))
-    W, B, _, sweeps = jax.lax.while_loop(cond, sweep, (W, B, delta, jnp.int32(1)))
-
-    # Recover Theta column-wise from the final (W, B).
-    def theta_col(j):
-        beta = B[:, j]
-        w12 = W[:, j].at[j].set(0.0)
-        t22 = 1.0 / (W[j, j] - w12 @ beta)
-        col = -beta * t22
-        return col.at[j].set(t22)
-
-    Theta = jax.vmap(theta_col, out_axes=1)(jnp.arange(b))
-    return 0.5 * (Theta + Theta.T), sweeps
+    lam = jnp.asarray(lam, S.dtype)
+    ST, W, BT, thr = fused_bcd_init(S, lam, scale, W0, Theta0, tol=tol)
+    ThetaT, sweeps = bcd_sweeps(
+        ST, W, BT, lam, thr,
+        max_sweeps=max_sweeps, n_cd=n_cd, node_screen=node_screen,
+    )
+    return 0.5 * (ThetaT.T + ThetaT), sweeps
 
 
 @functools.partial(

@@ -23,7 +23,10 @@ HBM traffic.
 Grid (npairs, nk): k streams (block_n, block_p) row-chunks of the SAME padded
 X at two column offsets (rank-block_n MXU updates accumulated in an f32 VMEM
 scratch, exactly the covgram schedule); the threshold/emit epilogue runs at
-k == nk-1.  lam rides in a (1, 1) block so a lambda sweep never recompiles;
+k == nk-1.  lam rides in a (1, 1) SMEM block so a lambda sweep never
+recompiles, and the per-pair count and stats are SMEM scalars ((1, 1, 1) and
+(1, 1, 2) blocks of (npairs, 1, ·) arrays — the TPU tiling rule accepts
+trailing block dims equal to the array's);
 the true n and p are static (one compile per dataset shape family).
 """
 
@@ -35,6 +38,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.mosaic import mosaic_trace
 
 
 def _kernel(
@@ -65,7 +70,11 @@ def _kernel(
     a = x_i_ref[...].astype(jnp.float32) - mu_i_ref[...].astype(jnp.float32)
     b = x_j_ref[...].astype(jnp.float32) - mu_j_ref[...].astype(jnp.float32)
     acc_ref[...] += jax.lax.dot_general(
-        a, b, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        a,
+        b,
+        (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )
 
     @pl.when(k == nk - 1)
@@ -82,9 +91,13 @@ def _kernel(
         lam = lam_ref[0, 0]
         mask = valid & (absS > lam)  # strict: eq. (4), ties are NOT edges
         vals_ref[0] = jnp.where(mask, S, 0.0)
-        cnt_ref[0, 0] = jnp.sum(mask.astype(jnp.int32)).astype(jnp.int32)
-        stat_ref[0, 0] = jnp.max(jnp.where(valid, absS, 0.0))
-        stat_ref[0, 1] = jnp.max(jnp.where(valid & ~mask, absS, 0.0))
+        # one axis at a time: Mosaic lowers a reduction straight to a scalar
+        # by retracing it with the caller's x64 setting, which widens an
+        # int32 sum to int64; row sums then a (1,) column sum stay int32
+        cnt = jnp.sum(mask.astype(jnp.int32), axis=1, keepdims=True)
+        cnt_ref[0, 0, 0] = jnp.sum(cnt, axis=0)[0]
+        stat_ref[0, 0, 0] = jnp.max(jnp.where(valid, absS, 0.0))
+        stat_ref[0, 0, 1] = jnp.max(jnp.where(valid & ~mask, absS, 0.0))
 
 
 @functools.partial(
@@ -125,24 +138,32 @@ def covgram_screen_pallas(
             pl.BlockSpec((block_n, block_p), lambda t, k, ii, jj: (k, jj[t])),
             pl.BlockSpec((1, block_p), lambda t, k, ii, jj: (0, ii[t])),
             pl.BlockSpec((1, block_p), lambda t, k, ii, jj: (0, jj[t])),
-            pl.BlockSpec((1, 1), lambda t, k, ii, jj: (0, 0)),
+            pl.BlockSpec(
+                (1, 1), lambda t, k, ii, jj: (0, 0), memory_space=pltpu.SMEM
+            ),
         ],
         out_specs=[
             pl.BlockSpec((1, block_p, block_p), lambda t, k, ii, jj: (t, 0, 0)),
-            pl.BlockSpec((1, 1), lambda t, k, ii, jj: (t, 0)),
-            pl.BlockSpec((1, 2), lambda t, k, ii, jj: (t, 0)),
+            pl.BlockSpec(
+                (1, 1, 1), lambda t, k, ii, jj: (t, 0, 0), memory_space=pltpu.SMEM
+            ),
+            pl.BlockSpec(
+                (1, 1, 2), lambda t, k, ii, jj: (t, 0, 0), memory_space=pltpu.SMEM
+            ),
         ],
         scratch_shapes=[pltpu.VMEM((block_p, block_p), jnp.float32)],
     )
-    return pl.pallas_call(
-        functools.partial(
-            _kernel, nk=nk, n_true=n_true, p_true=p_true, block_p=block_p
-        ),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((npairs, block_p, block_p), jnp.float32),
-            jax.ShapeDtypeStruct((npairs, 1), jnp.int32),
-            jax.ShapeDtypeStruct((npairs, 2), jnp.float32),
-        ],
-        interpret=interpret,
-    )(i_idx, j_idx, x, x, mu2, mu2, lam)
+    with mosaic_trace(interpret):
+        vals, counts, stats = pl.pallas_call(
+            functools.partial(
+                _kernel, nk=nk, n_true=n_true, p_true=p_true, block_p=block_p
+            ),
+            grid_spec=grid_spec,
+            out_shape=[
+                jax.ShapeDtypeStruct((npairs, block_p, block_p), jnp.float32),
+                jax.ShapeDtypeStruct((npairs, 1, 1), jnp.int32),
+                jax.ShapeDtypeStruct((npairs, 1, 2), jnp.float32),
+            ],
+            interpret=interpret,
+        )(i_idx, j_idx, x, x, mu2, mu2, lam)
+    return vals, counts.reshape(npairs, 1), stats.reshape(npairs, 2)

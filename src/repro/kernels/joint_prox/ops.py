@@ -15,7 +15,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.joint_prox.joint_prox import joint_prox_pallas
+from repro.kernels.joint_prox.joint_prox import joint_prox_pallas, joint_prox_slabs
+from repro.kernels.mosaic import row_tile
 from repro.kernels.joint_prox.ref import (  # noqa: F401  (re-export surface)
     PENALTIES,
     fused_prox,
@@ -50,7 +51,11 @@ def joint_prox_step(
 
         theta, u, z_old = padder(theta), padder(u), padder(z_old)
     t = jnp.stack([jnp.asarray(t1), jnp.asarray(t2)]).reshape(1, 2)
-    zn, un, acc = joint_prox_pallas(theta, u, z_old, t, penalty=penalty)
+    bp = b + pad
+    rt = row_tile(bp, bp * theta.dtype.itemsize, joint_prox_slabs(K, penalty))
+    zn, un, acc = joint_prox_pallas(
+        theta, u, z_old, t, penalty=penalty, row_tile=rt
+    )
     if pad:
         zn, un = zn[:, :b, :b], un[:, :b, :b]
     return zn, un, acc[0, 0], acc[0, 1]

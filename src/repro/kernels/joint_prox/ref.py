@@ -62,53 +62,71 @@ def group_prox(a: jnp.ndarray, t1, t2) -> jnp.ndarray:
 
 
 def tv_complete_prox(a: jnp.ndarray, t) -> jnp.ndarray:
-    """prox of t * sum_{k<k'} |x_k - x_k'| along axis 0 of a (K, ...) array.
-
-    Sort-free formulation (see module docstring): ranks via pairwise
-    comparisons, order statistics via one-hot sums, minimax isotonic fit,
-    rank-gather back.  All loops are over the STATIC class axis K."""
+    """prox of t * sum_{k<k'} |x_k - x_k'| along axis 0 of a (K, ...) array."""
     K = a.shape[0]
     if K == 1:
         return a
-    t = jnp.asarray(t, a.dtype)
-    tail = (1,) * (a.ndim - 1)
-    pos = jnp.arange(K).reshape((K,) + tail)
+    return jnp.stack(tv_complete_prox_classes([a[k] for k in range(K)], t))
+
+
+def tv_complete_prox_classes(xs: list, t) -> list:
+    """``tv_complete_prox`` on a list of K same-shape per-class arrays.
+
+    Sort-free formulation (see module docstring): ranks via pairwise
+    comparisons, order statistics via one-hot sums, minimax isotonic fit,
+    rank-gather back.  Every loop is over the STATIC class axis K and every
+    op is elementwise on one class's array, so the same code lowers inside
+    the Pallas kernel, which has no gather."""
+    K = len(xs)
+    if K == 1:
+        return list(xs)
+    dtype = xs[0].dtype
+    t = jnp.asarray(t, dtype)
     # stable rank: #(strictly smaller) + #(equal with smaller class index)
-    ai = a[:, None]
-    aj = a[None, :]
-    pi = pos[:, None]
-    pj = pos[None, :]
-    less = (aj < ai) | ((aj == ai) & (pj < pi))
-    rank = jnp.sum(less.astype(a.dtype), axis=1)  # (K, ...), values 0..K-1
-    # order statistics a_(r) via one-hot contraction
-    r_ids = jnp.arange(K, dtype=a.dtype).reshape((K,) + (1,) * a.ndim)
-    onehot = (rank[None] == r_ids).astype(a.dtype)  # (Kr, K, ...)
-    asort = jnp.sum(onehot * a[None], axis=1)  # (K, ...) ascending
-    # stationarity shift for strictly ordered coordinates
-    shift = t * (2.0 * jnp.arange(K, dtype=a.dtype) - (K - 1)).reshape((K,) + tail)
-    b = asort - shift
-    # prefix sums P[r] = sum of the first r shifted values; a static python
-    # loop instead of cumsum so the identical code lowers inside Pallas
-    parts = [jnp.zeros(a.shape[1:], a.dtype)]
+    ranks = []
+    for i in range(K):
+        r = jnp.zeros_like(xs[i])
+        for j in range(K):
+            if j != i:
+                less = (xs[j] <= xs[i]) if j < i else (xs[j] < xs[i])
+                r = r + less.astype(dtype)
+        ranks.append(r)  # values 0..K-1
+    # one-hot of each class's rank, and the order statistics a_(r)
+    onehot = [[(ranks[k] == r).astype(dtype) for k in range(K)] for r in range(K)]
+    asort = []
     for r in range(K):
-        parts.append(parts[-1] + b[r])
-    prefix = jnp.stack(parts)  # (K+1, ...)
-    # segment means M[j, l] = mean(b_j..b_l); only j <= l is ever read below
-    num = prefix[None, 1:] - prefix[:-1, None]  # (j, l, ...)
-    length = (
-        jnp.arange(K, dtype=a.dtype)[None, :] - jnp.arange(K, dtype=a.dtype)[:, None]
-        + 1.0
-    )
-    length = jnp.maximum(length, 1.0).reshape((K, K) + tail)
-    M = num / length
-    # isotonic fit via minimax: y_r = max_{j<=r} min_{l>=r} M[j, l]
+        acc = onehot[r][0] * xs[0]
+        for k in range(1, K):
+            acc = acc + onehot[r][k] * xs[k]
+        asort.append(acc)  # ascending in r
+    # stationarity shift for strictly ordered coordinates, and prefix sums
+    # P[r] = sum of the first r shifted values
+    prefix = [jnp.zeros_like(xs[0])]
+    for r in range(K):
+        prefix.append(prefix[-1] + (asort[r] - t * (2.0 * r - (K - 1))))
+    # segment means M[j][l] = mean(b_j..b_l) for j <= l, and the isotonic
+    # fit via minimax: y_r = max_{j<=r} min_{l>=r} M[j][l]
+    M = [
+        {l: (prefix[l + 1] - prefix[j]) / float(l - j + 1) for l in range(j, K)}
+        for j in range(K)
+    ]
     ys = []
     for r in range(K):
-        inner = jnp.min(M[:, r:], axis=1)  # min over l >= r, for every j
-        ys.append(jnp.max(inner[: r + 1], axis=0))
-    ysort = jnp.stack(ys)  # (K, ...) nondecreasing
+        best = None
+        for j in range(r + 1):
+            inner = M[j][r]
+            for l in range(r + 1, K):
+                inner = jnp.minimum(inner, M[j][l])
+            best = inner if best is None else jnp.maximum(best, inner)
+        ys.append(best)  # nondecreasing in r
     # gather back by rank
-    return jnp.sum(onehot * ysort[:, None], axis=0)
+    out = []
+    for k in range(K):
+        acc = onehot[0][k] * ys[0]
+        for r in range(1, K):
+            acc = acc + onehot[r][k] * ys[r]
+        out.append(acc)
+    return out
 
 
 def fused_prox(a: jnp.ndarray, t1, t2) -> jnp.ndarray:
@@ -122,6 +140,26 @@ def joint_prox_entries(a: jnp.ndarray, t1, t2, *, penalty: str) -> jnp.ndarray:
         return group_prox(a, t1, t2)
     if penalty == "fused":
         return fused_prox(a, t1, t2)
+    raise ValueError(f"unknown joint penalty {penalty!r}; available: {PENALTIES}")
+
+
+def joint_prox_classes(xs: list, t1, t2, *, penalty: str) -> list:
+    """``joint_prox_entries`` on a list of K per-class arrays (the Pallas
+    kernel's form: one (rows, b) tile per class, no stacked indexing)."""
+    if penalty == "group":
+        vs = [_soft(x, t1) for x in xs]
+        sq = vs[0] * vs[0]
+        for v in vs[1:]:
+            sq = sq + v * v
+        nrm = jnp.sqrt(sq)
+        scale = jnp.where(
+            nrm > 0.0,
+            jnp.maximum(1.0 - t2 / jnp.where(nrm > 0.0, nrm, 1.0), 0.0),
+            0.0,
+        )
+        return [v * scale for v in vs]
+    if penalty == "fused":
+        return [_soft(y, t1) for y in tv_complete_prox_classes(xs, t2)]
     raise ValueError(f"unknown joint penalty {penalty!r}; available: {PENALTIES}")
 
 

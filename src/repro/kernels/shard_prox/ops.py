@@ -15,7 +15,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.shard_prox.ref import fused_prox_ref
-from repro.kernels.shard_prox.shard_prox import fused_prox_pallas
+from repro.kernels.shard_prox.shard_prox import SHARD_PROX_SLABS, fused_prox_pallas
+from repro.kernels.mosaic import row_tile
 
 
 def _is_tpu() -> bool:
@@ -34,7 +35,9 @@ def fused_prox_residual(
     if pad_r or pad_c:
         padder = lambda m: jnp.pad(m, ((0, pad_r), (0, pad_c)))
         x_new, u, z_old = padder(x_new), padder(u), padder(z_old)
-    zn, un, acc = fused_prox_pallas(x_new, u, z_old, jnp.asarray(t))
+    rows, cols = rl + pad_r, b + pad_c
+    tr = row_tile(rows, cols * x_new.dtype.itemsize, SHARD_PROX_SLABS)
+    zn, un, acc = fused_prox_pallas(x_new, u, z_old, jnp.asarray(t), row_tile=tr)
     if pad_r or pad_c:
         zn, un = zn[:rl, :b], un[:rl, :b]
     return zn, un, acc[0, 0], acc[0, 1]

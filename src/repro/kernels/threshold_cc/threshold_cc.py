@@ -26,6 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.mosaic import mosaic_trace
+
 
 def _kernel(s_ref, lab_j_ref, lab_i_ref, lam_ref, o_ref, acc_ref, *, nj, block, p):
     i = pl.program_id(0)
@@ -66,18 +68,19 @@ def labelprop_step_pallas(
     lab_row = labels.reshape(P, 1)
     lab_col = labels.reshape(1, P)
 
-    out = pl.pallas_call(
-        functools.partial(_kernel, nj=nt, block=block, p=true_p),
-        grid=(nt, nt),
-        in_specs=[
-            pl.BlockSpec((block, block), lambda i, j: (i, j)),
-            pl.BlockSpec((1, block), lambda i, j: (0, j)),
-            pl.BlockSpec((block, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((block, 1), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((P, 1), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((block, 1), jnp.int32)],
-        interpret=interpret,
-    )(S, lab_col, lab_row, lam)
+    with mosaic_trace(interpret):
+        out = pl.pallas_call(
+            functools.partial(_kernel, nj=nt, block=block, p=true_p),
+            grid=(nt, nt),
+            in_specs=[
+                pl.BlockSpec((block, block), lambda i, j: (i, j)),
+                pl.BlockSpec((1, block), lambda i, j: (0, j)),
+                pl.BlockSpec((block, 1), lambda i, j: (i, 0)),
+                pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
+            ],
+            out_specs=pl.BlockSpec((block, 1), lambda i, j: (i, 0)),
+            out_shape=jax.ShapeDtypeStruct((P, 1), jnp.int32),
+            scratch_shapes=[pltpu.VMEM((block, 1), jnp.int32)],
+            interpret=interpret,
+        )(S, lab_col, lab_row, lam)
     return out[:, 0]

@@ -177,17 +177,10 @@ class HloModule:
     def _dot_flops(self, comp: str, type_str: str, line: str) -> float:
         res_elems = _elems(type_str)
         mdim = re.search(r"lhs_contracting_dims=\{([\d,]*)\}", line)
-        # lhs operand = first top-level argument of "dot(...)".  Newer XLA
-        # prints bare refs ("dot(%a, %b)"), older (<=0.4.x) prints the type
-        # inline ("dot(f32[128,256]{1,0} %a, ...)") — accept both: prefer the
-        # ref's recorded result type, fall back to the inline segment.
-        lhs = re.search(
-            r"\bdot\(\s*(?:(\w+\[[\d,]*\](?:\{[\d,:TS()]*\})?)\s+)?(%?[\w\.\-]+)",
-            line,
-        )
-        lhs_type = self.result_type.get(lhs.group(2), "") if lhs else ""
-        if not _SHAPE_RE.search(lhs_type) and lhs and lhs.group(1):
-            lhs_type = lhs.group(1)
+        # lhs operand = first top-level argument of "dot(%a, %b)"; its type
+        # is the ref's recorded result type
+        lhs = re.search(r"\bdot\(\s*(%?[\w\.\-]+)", line)
+        lhs_type = self.result_type.get(lhs.group(1), "") if lhs else ""
         contract = 1
         if mdim:
             dims_m = _SHAPE_RE.search(lhs_type)

@@ -117,7 +117,7 @@ microseconds-cheap), and the batcher dispatches it down the executor's
 sharded route: shard-direct gather, the mesh-spanning no-eigh ADMM,
 distributed KKT verification, single-device iterative fallback on
 rejection.  ``GlassoResult.oversize`` carries the per-request
-{dispatched, inner_iters, fallbacks}.
+{dispatched, inner_iters, stalls, fallbacks}.
 """
 
 from __future__ import annotations
@@ -1207,7 +1207,10 @@ class GlassoServer:
                     stacks.append(out_pb)
                     acc = oversize_by_req.setdefault(
                         id(pb.request),
-                        {"dispatched": 0, "inner_iters": 0, "fallbacks": 0},
+                        {
+                            "dispatched": 0, "inner_iters": 0, "stalls": 0,
+                            "fallbacks": 0,
+                        },
                     )
                     for k in acc:
                         acc[k] += info[k]
@@ -1376,6 +1379,9 @@ def serve_stats() -> dict[str, int | float]:
 def main():
     import jax
 
+    from repro.launch.compile_cache import use_checkout_cache
+
+    use_checkout_cache()
     jax.config.update("jax_enable_x64", True)
 
     ap = argparse.ArgumentParser()

@@ -206,3 +206,28 @@ def test_fused_from_serving_routes_unchanged():
     finally:
         set_route("general", "iterative")
     assert np.array_equal(np.asarray(offline.Theta), np.asarray(served.Theta))
+
+
+
+@pytest.mark.parametrize("fused", [False, True])
+def test_warm_paths_never_read_a_donated_w0(monkeypatch, fused):
+    """Warm W0 stacks are donated to the solver call off the CPU.  Force
+    donation on here (the CPU backend honours it: a donated buffer is
+    deleted) and run a warm path whose closed-form and chordal candidates
+    are all rejected (route_check_tol=0), so repairs warm-start from donated
+    stacks too: no W0 may be read after its call, and the answers equal the
+    undonated run's."""
+    from repro.covariance import structured_synthetic
+    from repro.engine import executor
+
+    S = structured_synthetic(12, 8, seed=5)
+    lams = [0.7, 0.55, 0.4, 0.32]
+    opts = EngineOptions(fused=fused, route_check_tol=0.0)
+    reset("router")
+    plain = glasso_path(S, lams, options=opts)
+    assert count("router.fallback.tree") + count("router.fallback.chordal") > 0
+
+    monkeypatch.setattr(executor, "_COMPILED", {})
+    monkeypatch.setattr(executor, "_donate_supported", lambda: True)
+    donated = glasso_path(S, lams, options=opts)
+    assert _path_bitwise_equal(plain, donated)

@@ -68,10 +68,7 @@ def test_matches_cost_analysis_on_scanfree_graph():
     w2 = jax.ShapeDtypeStruct((1024, 256), jnp.float32)
     compiled = _compile(fn, x, w1, w2)
     ours = analyze_hlo(compiled.as_text())["flops"]
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # jax <= 0.4.x returns [dict]
-        ca = ca[0]
-    xla = ca["flops"]
+    xla = compiled.cost_analysis()["flops"]
     assert abs(ours - xla) / xla < 0.05, (ours, xla)
 
 

@@ -70,7 +70,11 @@ def test_covgram_screen_pallas_matches_ref(n, p, seed, q):
     Xc = X - mu
     S = Xc.T @ Xc / n
     iu, ju = np.triu_indices(p, 1)
-    lam = float(np.quantile(np.abs(S[iu, ju]), q)) if p > 1 else 0.1
+    # the kernel thresholds in float32 and the oracle in float64, so lam sits
+    # midway between two adjacent |S_ij| (an exact tie could round either way)
+    a = np.sort(np.abs(S[iu, ju]))
+    k = int(q * (a.size - 1))
+    lam = float(0.5 * (a[k] + a[k + 1]))
     x_pad, mu_pad = pad_for_screen(X, mu, block_n=bn, block_p=bp)
     nt = x_pad.shape[1] // bp
     ti, tj = np.triu_indices(nt)

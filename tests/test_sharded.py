@@ -24,6 +24,7 @@ from repro.core.solvers import (
     glasso_sharded,
     solver_spec,
 )
+from repro.core.solvers.closed_form import kkt_residual_host
 from repro.core.solvers.sharded import sharded_pad_size
 from repro.kernels.shard_prox.ref import fused_prox_ref
 from repro.kernels.shard_prox.shard_prox import fused_prox_pallas
@@ -43,6 +44,27 @@ def test_sharded_matches_admm_oracle(p, seed, q):
     assert res.kkt_residual <= 1e-6 * max(1.0, res.s_max)
     np.testing.assert_allclose(res.Theta, ref, atol=1e-6)
     assert ((np.abs(res.Theta) > 1e-9) == (np.abs(ref) > 1e-9)).all()
+
+
+def test_sharded_float32_stops_at_its_rounding_floor():
+    """float32 (the chip's dtype) cannot reach float64's inner tolerances:
+    the solve must stop on its rounding floors, not spin to max_iter or
+    revert every outer step, and still verify at a float32 KKT target."""
+    rng = np.random.default_rng(3)
+    X = rng.standard_normal((256, 128))
+    X[:, 1:] += 0.8 * X[:, :-1]  # a chain: one connected component
+    S = np.cov(X, rowvar=False, bias=True)
+    lam = 0.2
+    res = glasso_sharded(S, lam, dtype=jnp.float32, kkt_target=1e-4)
+    assert res.iters < 6000 and res.retries == 0
+    assert res.kkt_residual <= 1e-4 * max(1.0, res.s_max)
+    kkt = kkt_residual_host(S, lam, np.asarray(res.Theta, dtype=np.float64))
+    assert kkt <= 1e-3 * max(1.0, np.abs(S).max())
+    # the float32 eps (tol * b) is under rounding noise: the stall stop ended
+    # the ADMM loop, above that eps, and says so
+    assert res.stalls >= 1 and res.admm_residual > res.admm_eps
+    res64 = glasso_sharded(S, lam, kkt_target=1e-6)
+    assert res64.stalls == 0
 
 
 def test_sharded_pad_size():

@@ -77,7 +77,8 @@ def test_streamed_ties_are_not_edges(seed):
     S = _dense_S(X)
     iu, ju = np.triu_indices(40, 1)
     vals = np.abs(S[iu, ju])
-    lam = float(np.median(vals[vals > 0]))  # an exact |S_ij|: a true tie
+    pos = np.sort(vals[vals > 0])
+    lam = float(pos[pos.size // 2])  # an exact |S_ij|: a true tie
     assert (vals == lam).any()
     sc = stream_screen(X, [lam], config=CFG)
     labels, stats = thresholded_components(S, lam)
