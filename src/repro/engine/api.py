@@ -372,7 +372,6 @@ class Engine:
             route=opts.route,
             route_check_tol=opts.route_check_tol,
             fused=fused,
-            jax_annotations=opts.trace == "jax",
         )
 
     def _trace_ctx(self, name: str, **attrs):
@@ -636,7 +635,7 @@ class Engine:
         screen covers the whole grid (Theorem 2 — the compacted edges above
         the grid minimum determine every partition), then the standard
         diffed-plan execution runs over materialized blocks."""
-        from repro.stream import plan_path_streaming
+        from repro.stream import plan_path_from_screen, stream_screen
 
         if stream is None:
             stream = self.stream
@@ -645,11 +644,13 @@ class Engine:
             "engine.path", n_lams=len(lambdas), p=int(np.shape(X)[1]),
             source="data",
         ):
+            with span("engine.screen", backend="stream"):
+                sc = stream_screen(
+                    X, lambdas, config=stream, oversize=self.oversize
+                )
             with span("engine.plan", backend="stream"):
-                path, sc = plan_path_streaming(
-                    X,
-                    lambdas,
-                    config=stream,
+                path = plan_path_from_screen(
+                    sc,
                     dtype=self.np_dtype,
                     classify_structures=self.executor.route,
                     oversize=self.oversize,

@@ -430,10 +430,6 @@ class BucketExecutor:
     # bucket_glasso launch per size bin (resolved to a bool by the Engine
     # from EngineOptions.fused; buckets routed "fused" fuse regardless)
     fused: bool = False
-    # EngineOptions(trace="jax"): wrap each solve_plan dispatch wave in a
-    # jax.profiler.TraceAnnotation so device-side profiler timelines line
-    # up with the host span tree
-    jax_annotations: bool = False
     # bucket_key -> previous padded solution / input stacks (device arrays):
     # reused buckets warm-start from their own previous solution and skip the
     # host->device re-upload of their bit-identical padded blocks.
@@ -613,34 +609,6 @@ class BucketExecutor:
         (``registry.route_for``), every non-iterative candidate is
         KKT-verified, and failures are re-dispatched to the iterative solver
         before assembly — see ``_verify_and_fallback``."""
-        if self.jax_annotations:
-            from jax.profiler import TraceAnnotation
-
-            with TraceAnnotation("glasso.solve_plan"):
-                return self._solve_plan(
-                    plan, lam, S, warm_W=warm_W, warm_Theta=warm_Theta,
-                    reused_keys=reused_keys, keep_solutions=keep_solutions,
-                    output=output, priorities=priorities,
-                )
-        return self._solve_plan(
-            plan, lam, S, warm_W=warm_W, warm_Theta=warm_Theta,
-            reused_keys=reused_keys, keep_solutions=keep_solutions,
-            output=output, priorities=priorities,
-        )
-
-    def _solve_plan(
-        self,
-        plan: blocks_mod.Plan,
-        lam: float,
-        S: np.ndarray,
-        *,
-        warm_W: np.ndarray | None = None,
-        warm_Theta: np.ndarray | None = None,
-        reused_keys: frozenset = frozenset(),
-        keep_solutions: bool = False,
-        output: str = "dense",
-        priorities=None,
-    ) -> np.ndarray:
         from repro.engine.planner import bucket_key  # local: avoid cycle at import
         from repro.engine.registry import route_for  # local: avoid cycle at import
 

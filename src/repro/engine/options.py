@@ -73,10 +73,10 @@ class EngineOptions:
     fused: bool | str = "auto"
     # observability (DESIGN.md Section 17): True roots a request Trace per
     # run/run_path (spans: screen -> plan -> per-step solve -> dispatch ->
-    # assemble) attached as ``GlassoResult.trace``; False makes the engine
-    # span-free (the <5%-overhead bench arm); "jax" additionally wraps each
-    # dispatch wave in ``jax.profiler.TraceAnnotation`` so device-side
-    # profiler timelines correlate with the host span tree
+    # assemble) attached as ``GlassoResult.trace``, each span also a
+    # ``jax.profiler.TraceAnnotation`` so device profiles show the host
+    # span tree; False makes the engine span-free (the <5%-overhead bench
+    # arm); "jax" is accepted and means the same as True
     trace: bool | str = True
     solver_opts: Mapping[str, Any] = field(default_factory=dict)
 

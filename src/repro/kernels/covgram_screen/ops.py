@@ -22,8 +22,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.instrument import bump
 from repro.kernels.covgram_screen.covgram_screen import covgram_screen_pallas
 from repro.kernels.covgram_screen.ref import covgram_screen_ref
+from repro.obs.trace import span
 
 
 def _is_tpu() -> bool:
@@ -70,38 +72,60 @@ def covgram_screen_tiles(
 
     x_pad/mu_pad follow ``pad_for_screen``'s convention.  Returns host
     arrays (vals (B, bp, bp), counts (B,), stats (B, 2)) — see the kernel
-    docstring for the stats layout."""
+    docstring for the stats layout.
+
+    Under an active trace the call records ``screen.upload`` (inputs to
+    the device), ``screen.kernel`` (the kernel, to completion) and
+    ``screen.fetch`` (results back to the host) spans; the bytes moved
+    each way count in ``stream.upload_bytes`` / ``stream.fetch_bytes``
+    (0 on the host oracle)."""
     if backend == "auto":
         backend = "pallas" if _is_tpu() else "ref"
     i_idx = np.asarray(i_idx, np.int32)
     j_idx = np.asarray(j_idx, np.int32)
     if backend == "ref":
-        vals, counts, stats = covgram_screen_ref(
-            np.asarray(x_pad),
-            np.asarray(mu_pad),
-            i_idx,
-            j_idx,
-            float(lam),
-            n_true=n_true,
-            p_true=p_true,
-            block_p=block_p,
-        )
-        return vals, counts[:, 0], stats
+        with span("screen.upload"):
+            x_host, mu_host = np.asarray(x_pad), np.asarray(mu_pad)
+        with span("screen.kernel"):
+            vals, counts, stats = covgram_screen_ref(
+                x_host,
+                mu_host,
+                i_idx,
+                j_idx,
+                float(lam),
+                n_true=n_true,
+                p_true=p_true,
+                block_p=block_p,
+            )
+        with span("screen.fetch"):
+            counts = counts[:, 0]
+        bump("stream.upload_bytes", 0)
+        bump("stream.fetch_bytes", 0)
+        return vals, counts, stats
     if backend != "pallas":
         raise ValueError(f"unknown covgram_screen backend {backend!r}")
-    vals, counts, stats = covgram_screen_pallas(
-        jnp.asarray(x_pad, jnp.float32),
-        jnp.asarray(mu_pad, jnp.float32),
-        jnp.asarray(i_idx),
-        jnp.asarray(j_idx),
-        jnp.asarray(float(lam), jnp.float32).reshape(1, 1),
-        n_true=n_true,
-        p_true=p_true,
-        block_n=block_n,
-        block_p=block_p,
-        interpret=not _is_tpu(),
-    )
-    return np.asarray(vals), np.asarray(counts)[:, 0], np.asarray(stats)
+    with span("screen.upload"):
+        args = jax.block_until_ready((
+            jnp.asarray(x_pad, jnp.float32),
+            jnp.asarray(mu_pad, jnp.float32),
+            jnp.asarray(i_idx),
+            jnp.asarray(j_idx),
+            jnp.asarray(float(lam), jnp.float32).reshape(1, 1),
+        ))
+    with span("screen.kernel"):
+        out = jax.block_until_ready(covgram_screen_pallas(
+            *args,
+            n_true=n_true,
+            p_true=p_true,
+            block_n=block_n,
+            block_p=block_p,
+            interpret=not _is_tpu(),
+        ))
+    with span("screen.fetch"):
+        vals, counts, stats = (np.asarray(a) for a in out)
+    bump("stream.upload_bytes", sum(a.nbytes for a in args))
+    bump("stream.fetch_bytes", vals.nbytes + counts.nbytes + stats.nbytes)
+    return vals, counts[:, 0], stats
 
 
 def compact_edges(

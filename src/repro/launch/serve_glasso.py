@@ -338,7 +338,6 @@ class GlassoServer:
             solver_opts=dict(solver_opts),
             route=True,
             route_check_tol=self.route_check_tol,
-            jax_annotations=opts.trace == "jax",
         )
         # data sessions: named streaming-screen states for append_rows; the
         # session executor honors the server's route setting (the admission
@@ -349,7 +348,6 @@ class GlassoServer:
             solver_opts=dict(solver_opts),
             route=opts.route,
             route_check_tol=self.route_check_tol,
-            jax_annotations=opts.trace == "jax",
         )
         self._sessions: dict[str, _SessionEntry] = {}
         self._sessions_lock = threading.Lock()

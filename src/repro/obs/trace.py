@@ -25,6 +25,12 @@ Propagation rules (DESIGN.md Section 17):
 All timestamps come from ``time.perf_counter()`` — monotonic, so span
 durations never go negative across wall-clock adjustments (the ruff
 TID251 gate bans the wall clock in ``src/`` for exactly this reason).
+
+Every recorded span (and a rooting ``trace_request``) also enters a
+``jax.profiler.TraceAnnotation`` of the same name on the same thread, so
+a ``jax.profiler`` trace shows the span tree in its host plane, on the
+profiler's own clock beside the device's program executions.  Nothing is
+annotated while no trace is active.
 """
 
 from __future__ import annotations
@@ -36,6 +42,8 @@ import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Any, Iterator
+
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "Span",
@@ -245,7 +253,8 @@ def span(name: str, **attrs: Any) -> Iterator[Span | None]:
     span_id = trace.begin(name, parent_id=parent_id, **attrs)
     reset = _CURRENT.set((trace, span_id))
     try:
-        yield trace.spans[span_id]
+        with TraceAnnotation(name):
+            yield trace.spans[span_id]
     finally:
         _CURRENT.reset(reset)
         trace.end(span_id)
@@ -263,7 +272,8 @@ def trace_request(name: str, **attrs: Any) -> Iterator[Trace]:
     trace = Trace(name, **attrs)
     reset = _CURRENT.set((trace, trace.root_id))
     try:
-        yield trace
+        with TraceAnnotation(name):
+            yield trace
     finally:
         _CURRENT.reset(reset)
         trace.finish()

@@ -23,6 +23,13 @@ Peak memory is  O(p * tile + #edges)  (in-flight tile batch + edge store +
 O(p) moments/labels), recorded live in the ``stream.bytes_peak`` watermark;
 the exactness story is unchanged — the emitted partition is property-tested
 identical to ``thresholded_components`` on a dense S, ties included.
+
+Under an active trace each stage records a span: ``screen.moments``
+(moments, schedule, padding), per batch ``screen.upload`` /
+``screen.kernel`` / ``screen.fetch`` (``covgram_screen_tiles``) and
+``screen.compact``, then ``screen.sweep`` and ``screen.materialize``;
+``stream.tiles_with_edges`` counts the computed tile pairs that held an
+edge.
 """
 
 from __future__ import annotations
@@ -40,6 +47,7 @@ from repro.kernels.covgram_screen import (
     covgram_screen_tiles,
     pad_for_screen,
 )
+from repro.obs.trace import span
 from repro.stream.accumulate import EdgeAccumulator
 from repro.stream.config import StreamConfig, as_config
 from repro.stream.materialize import MaterializedCovariance, materialize_components
@@ -94,18 +102,21 @@ def stream_screen(
     lams = normalize_lambda_grid(lambdas)
     lam_min = lams[-1]
 
-    moments = column_moments(X, chunk=cfg.chunk)
-    norms_max = tile_maxima(moments.norms, cfg.tile)
-    ti, tj, keep = tile_pair_schedule(
-        norms_max, lam_min, slack=cfg.skip_slack
-    )
+    with span("screen.moments"):
+        moments = column_moments(X, chunk=cfg.chunk)
+        norms_max = tile_maxima(moments.norms, cfg.tile)
+        ti, tj, keep = tile_pair_schedule(
+            norms_max, lam_min, slack=cfg.skip_slack
+        )
+        x_pad, mu_pad = pad_for_screen(
+            X, moments.mu, block_n=cfg.chunk, block_p=cfg.tile
+        )
     bump("stream.tiles_total", int(ti.size))
     bump("stream.tiles_skipped", int((~keep).sum()))
 
     acc = EdgeAccumulator(keep_tiles=keep_tile_stats)
     acc.add_skipped(zip(ti[~keep], tj[~keep]))
 
-    x_pad, mu_pad = pad_for_screen(X, moments.mu, block_n=cfg.chunk, block_p=cfg.tile)
     itemsize = 4 if cfg.backend == "pallas" else x_pad.dtype.itemsize
     batch = cfg.resolved_pair_batch(itemsize)
     i_keep = ti[keep].astype(np.int32)
@@ -115,7 +126,7 @@ def stream_screen(
     for b0 in range(0, i_keep.size, batch):
         bi = i_keep[b0 : b0 + batch]
         bj = j_keep[b0 : b0 + batch]
-        vals, _, stats = covgram_screen_tiles(
+        vals, counts, stats = covgram_screen_tiles(
             x_pad,
             mu_pad,
             bi,
@@ -127,37 +138,38 @@ def stream_screen(
             block_n=cfg.chunk,
             backend=cfg.backend,
         )
-        gi, gj, w = compact_edges(vals, bi, bj, block_p=cfg.tile)
-        acc.add_batch(bi, bj, gi, gj, w, stats, tile=cfg.tile)
+        bump("stream.tiles_with_edges", int(np.count_nonzero(counts)))
+        with span("screen.compact"):
+            gi, gj, w = compact_edges(vals, bi, bj, block_p=cfg.tile)
+            acc.add_batch(bi, bj, gi, gj, w, stats, tile=cfg.tile)
         local_peak = max(local_peak, base_bytes + vals.nbytes + acc.bytes_held())
         set_peak("stream.bytes_peak", local_peak)
     bump("stream.edges_emitted", acc.n_edges)
 
-    ei, ej, ew = acc.edges()
-    order = np.argsort(-ew, kind="stable")
-    edges = (ei[order], ej[order], ew[order])
-    labels = labels_at_thresholds_from_edges(p, lams, edges)
-
-    seconds = time.perf_counter() - t0
-    per_lam = seconds / max(len(lams), 1)
-    stats_list = []
-    for lam, lab in zip(lams, labels):
-        _, counts = np.unique(lab, return_counts=True)
-        stats_list.append(
-            ScreenStats(
-                lam=lam,
-                n_components=int(counts.size),
-                max_comp=int(counts.max()),
-                n_isolated=int((counts == 1).sum()),
-                # edges sorted descending; strict |S_ij| > lam (eq. (4))
-                n_edges=int(np.searchsorted(-edges[2], -lam, side="left")),
-                seconds=per_lam,
-                tiles_total=int(ti.size),
-                tiles_skipped=int((~keep).sum()),
-                edges_emitted=acc.n_edges,
-                bytes_peak=0,  # filled below once materialization lands
+    with span("screen.sweep"):
+        ei, ej, ew = acc.edges()
+        order = np.argsort(-ew, kind="stable")
+        edges = (ei[order], ej[order], ew[order])
+        labels = labels_at_thresholds_from_edges(p, lams, edges)
+        stats_list = []
+        for lam, lab in zip(lams, labels):
+            _, sizes = np.unique(lab, return_counts=True)
+            stats_list.append(
+                ScreenStats(
+                    lam=lam,
+                    n_components=int(sizes.size),
+                    max_comp=int(sizes.max()),
+                    n_isolated=int((sizes == 1).sum()),
+                    # edges sorted descending; strict |S_ij| > lam (eq. (4))
+                    n_edges=int(np.searchsorted(-edges[2], -lam, side="left")),
+                    seconds=0.0,  # filled below: the stream's share per lambda
+                    tiles_total=int(ti.size),
+                    tiles_skipped=int((~keep).sum()),
+                    edges_emitted=acc.n_edges,
+                    bytes_peak=0,  # filled below once materialization lands
+                )
             )
-        )
+    per_lam = (time.perf_counter() - t0) / max(len(lams), 1)
 
     S = None
     if materialize:
@@ -168,12 +180,14 @@ def stream_screen(
         # O(#edges) work per call — that incremental structure is the
         # session layer's tool, where edge sets arrive per-tile
         # (stream.session / stream.unionfind).
-        S = materialize_components(
-            X, moments.mu, moments.diag, labels[-1], oversize=oversize
-        )
+        with span("screen.materialize"):
+            S = materialize_components(
+                X, moments.mu, moments.diag, labels[-1], oversize=oversize
+            )
         local_peak = max(local_peak, base_bytes + acc.bytes_held() + S.nbytes())
         set_peak("stream.bytes_peak", local_peak)
     for st in stats_list:
+        st.seconds = per_lam
         st.bytes_peak = local_peak
     seconds = time.perf_counter() - t0
     return StreamScreen(

@@ -117,6 +117,75 @@ def test_chrome_export_valid(tmp_path):
     assert len(tr.to_dict()["spans"]) == 3
 
 
+class _RecordingAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs each enter and
+    exit with its thread."""
+
+    log: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        self.log.append(("enter", self.name, threading.get_ident()))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name, threading.get_ident()))
+        return False
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    from repro.obs import trace as obs_trace
+
+    _RecordingAnnotation.log = []
+    monkeypatch.setattr(obs_trace, "TraceAnnotation", _RecordingAnnotation)
+    return _RecordingAnnotation.log
+
+
+def test_spans_enter_profiler_annotations(annotations):
+    """Every span, and the rooting trace_request, is also a profiler
+    annotation of the same name, entered and left on its own thread."""
+
+    def worker(token):
+        with activate(token):
+            with span("worker.stage"):
+                pass
+
+    with trace_request("req") as tr:
+        with span("outer"):
+            with span("inner"):
+                pass
+        t = threading.Thread(target=worker, args=(context_token(),))
+        t.start()
+        t.join()
+    assert [(op, name) for op, name, _ in annotations] == [
+        ("enter", "req"), ("enter", "outer"), ("enter", "inner"),
+        ("exit", "inner"), ("exit", "outer"),
+        ("enter", "worker.stage"), ("exit", "worker.stage"), ("exit", "req"),
+    ]
+    threads = {}
+    for op, name, tid in annotations:
+        threads.setdefault(name, set()).add(tid)
+    assert all(len(tids) == 1 for tids in threads.values())
+    assert threads["worker.stage"] != threads["req"]
+    assert {s.name for s in tr.spans} == set(threads)
+
+
+def test_no_annotation_without_an_active_trace(annotations):
+    with span("orphan"):
+        pass
+    with trace_request("req"):
+        with trace_request("nested"):  # degrades to a child span
+            pass
+    assert [name for op, name, _ in annotations if op == "enter"] == ["req", "nested"]
+    annotations.clear()
+    with span("orphan.again"):
+        pass
+    assert annotations == []
+
+
 # ---------------------------------------------------------------------------
 # metrics registry
 # ---------------------------------------------------------------------------
@@ -308,6 +377,29 @@ def test_engine_options_trace_validation():
     assert EngineOptions(trace="jax").trace == "jax"
     with pytest.raises(ValueError, match="trace"):
         EngineOptions(trace="chrome")
+
+
+def test_trace_jax_means_true(annotations):
+    """``trace="jax"`` is kept for old callers: it traces exactly as True,
+    and every engine span reaches the profiler either way."""
+    from repro.covariance import lambda_interval_for_k, paper_synthetic
+    from repro.engine.api import Engine
+    from repro.engine.options import EngineOptions
+
+    S = paper_synthetic(3, 6, seed=4)
+    lo, hi = lambda_interval_for_k(S, 3)
+    names = []
+    for flag in (True, "jax"):
+        annotations.clear()
+        r = Engine(options=EngineOptions(trace=flag)).run(S, float(0.5 * (lo + hi)))
+        assert r.trace is not None
+        entered = [name for op, name, _ in annotations if op == "enter"]
+        assert entered == [s.name for s in r.trace.spans]
+        names.append(entered)
+    assert names[0] == names[1]
+    annotations.clear()
+    assert Engine(options=EngineOptions(trace=False)).run(S, float(0.5 * (lo + hi))).trace is None
+    assert annotations == []
 
 
 def test_select_path_roots_a_trace():
