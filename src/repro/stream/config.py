@@ -4,9 +4,10 @@ One knob object threads through the whole subsystem (tiler, driver, path
 adapter, serving sessions).  The memory model it controls (DESIGN.md
 Section 10):
 
-    peak screening bytes  ~=  pair_batch * tile^2 * itemsize   (in-flight tiles)
-                            + 3 * 8 * #edges                   (compacted edges)
-                            + O(p)                             (moments, labels)
+    peak screening bytes  ~=  pair_batch * tile^2 * itemsize   (in-flight tiles;
+                                                                device on the kernel)
+                            + 3 * 8 * #edges                   (compacted edges, host)
+                            + O(p)                             (moments, labels, host)
 
 so ``memory_budget_mb`` simply solves for ``pair_batch``.  The dense (p, p)
 covariance never exists; ``stream.bytes_peak`` (instrument watermark) records

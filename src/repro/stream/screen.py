@@ -10,7 +10,8 @@
   3. STREAM    surviving pairs flow in bounded batches through the fused
                covgram_screen kernel (Pallas on TPU, numpy oracle off-TPU);
                each batch compacts to (i, j, |S_ij|) triples in the edge
-               accumulator;
+               accumulator — on the device on the Pallas path, so only
+               the triples reach the host (``covgram_screen_edges``);
   4. SNAPSHOT  the retained edges, sorted once, replay the planner's nested
                Theorem-2 sweep (``labels_at_thresholds_from_edges``) — one
                incremental union-find pass labeling every requested lambda,
@@ -20,14 +21,17 @@
                the grid can request.
 
 Peak memory is  O(p * tile + #edges)  (in-flight tile batch + edge store +
-O(p) moments/labels), recorded live in the ``stream.bytes_peak`` watermark;
+O(p) moments/labels), recorded live in the ``stream.bytes_peak`` watermark.
+On the Pallas path the in-flight batch lives on the device and the host
+holds O(#edges) beside X and its moments;
 the exactness story is unchanged — the emitted partition is property-tested
 identical to ``thresholded_components`` on a dense S, ties included.
 
 Under an active trace each stage records a span: ``screen.moments``
 (moments, schedule, padding), per batch ``screen.upload`` /
-``screen.kernel`` / ``screen.fetch`` (``covgram_screen_tiles``) and
-``screen.compact``, then ``screen.sweep`` and ``screen.materialize``;
+``screen.kernel`` / ``screen.fetch`` / ``screen.compact``
+(``covgram_screen_edges``; ``screen.compact`` again around the
+accumulator), then ``screen.sweep`` and ``screen.materialize``;
 ``stream.tiles_with_edges`` counts the computed tile pairs that held an
 edge.
 """
@@ -43,9 +47,9 @@ from repro.core.instrument import bump, set_peak
 from repro.core.partition import labels_at_thresholds_from_edges
 from repro.core.screening import ScreenStats
 from repro.kernels.covgram_screen import (
-    compact_edges,
-    covgram_screen_tiles,
+    covgram_screen_edges,
     pad_for_screen,
+    resolve_backend,
 )
 from repro.obs.trace import span
 from repro.stream.accumulate import EdgeAccumulator
@@ -117,16 +121,20 @@ def stream_screen(
     acc = EdgeAccumulator(keep_tiles=keep_tile_stats)
     acc.add_skipped(zip(ti[~keep], tj[~keep]))
 
-    itemsize = 4 if cfg.backend == "pallas" else x_pad.dtype.itemsize
+    backend = resolve_backend(cfg.backend)
+    itemsize = 4 if backend == "pallas" else x_pad.dtype.itemsize
     batch = cfg.resolved_pair_batch(itemsize)
     i_keep = ti[keep].astype(np.int32)
     j_keep = tj[keep].astype(np.int32)
-    base_bytes = x_pad.nbytes + 4 * p * 8  # padded X + moments vectors
+    # stream.bytes_peak: padded X and the moments vectors (host), the
+    # in-flight tile batch (on the device on the Pallas path, on the host
+    # on the oracle) and the edge store (host)
+    base_bytes = x_pad.nbytes + 4 * p * 8
     local_peak = base_bytes
     for b0 in range(0, i_keep.size, batch):
         bi = i_keep[b0 : b0 + batch]
         bj = j_keep[b0 : b0 + batch]
-        vals, counts, stats = covgram_screen_tiles(
+        gi, gj, v, counts, stats = covgram_screen_edges(
             x_pad,
             mu_pad,
             bi,
@@ -136,13 +144,13 @@ def stream_screen(
             p_true=p,
             block_p=cfg.tile,
             block_n=cfg.chunk,
-            backend=cfg.backend,
+            backend=backend,
         )
         bump("stream.tiles_with_edges", int(np.count_nonzero(counts)))
         with span("screen.compact"):
-            gi, gj, w = compact_edges(vals, bi, bj, block_p=cfg.tile)
-            acc.add_batch(bi, bj, gi, gj, w, stats, tile=cfg.tile)
-        local_peak = max(local_peak, base_bytes + vals.nbytes + acc.bytes_held())
+            acc.add_batch(bi, bj, gi, gj, np.abs(v), stats, tile=cfg.tile)
+        batch_bytes = bi.size * cfg.tile**2 * itemsize
+        local_peak = max(local_peak, base_bytes + batch_bytes + acc.bytes_held())
         set_peak("stream.bytes_peak", local_peak)
     bump("stream.edges_emitted", acc.n_edges)
 

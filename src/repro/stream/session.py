@@ -39,11 +39,7 @@ import numpy as np
 from repro.core.instrument import bump
 from repro.core.screening import ScreenStats
 from repro.obs.trace import span
-from repro.kernels.covgram_screen import (
-    compact_edges,
-    covgram_screen_tiles,
-    pad_for_screen,
-)
+from repro.kernels.covgram_screen import covgram_screen_edges, pad_for_screen
 from repro.stream.accumulate import bin_edges_to_records
 from repro.stream.config import as_config
 from repro.stream.materialize import MaterializedCovariance, materialize_components
@@ -185,14 +181,13 @@ class DataSession:
             inv_j = np.array([t for _, t in invalid], dtype=np.int32)
             for b0 in range(0, inv_i.size, batch):
                 bi, bj = inv_i[b0 : b0 + batch], inv_j[b0 : b0 + batch]
-                vals, _, stats = covgram_screen_tiles(
+                gi, gj, v, _, stats = covgram_screen_edges(
                     x_pad, mu_pad, bi, bj, lam,
                     n_true=n2, p_true=p, block_p=tile, block_n=cfg.chunk,
                     backend=cfg.backend,
                 )
-                gi, gj, w = compact_edges(vals, bi, bj, block_p=tile)
                 fresh = bin_edges_to_records(
-                    bi, bj, gi, gj, w, stats, tile=tile
+                    bi, bj, gi, gj, np.abs(v), stats, tile=tile
                 )
                 self.tiles.update(fresh)
                 for rec in fresh.values():

@@ -16,6 +16,7 @@ from repro.core.instrument import counts
 from repro.covariance import microarray_like
 from repro.engine.options import EngineOptions
 from repro.kernels.covgram_screen import pad_for_screen
+from repro.kernels.covgram_screen.ops import _capacity
 from repro.stream import StreamConfig, stream_screen
 from repro.stream.tiler import column_moments
 
@@ -83,12 +84,14 @@ def test_screen_spans_are_children_of_engine_screen(path_trace):
         if s.parent_id is not None:
             assert s.seconds <= by_id[s.parent_id].seconds
     assert sum(s.seconds for s in children) <= screen.seconds
-    # one upload / kernel / fetch / compact per tile batch
+    # one upload / kernel / fetch per tile batch (the host oracle on the
+    # CPU), and two compact spans: the compaction, then the accumulator
     per_batch = [
         sum(s.name == n for s in children)
-        for n in ("screen.upload", "screen.kernel", "screen.fetch", "screen.compact")
+        for n in ("screen.upload", "screen.kernel", "screen.fetch")
     ]
     assert len(set(per_batch)) == 1 and per_batch[0] >= 2
+    assert sum(s.name == "screen.compact" for s in children) == 2 * per_batch[0]
 
 
 @pytest.mark.parametrize("backend", ["ref", "pallas"])
@@ -121,6 +124,8 @@ def test_screen_counters_match_shapes_and_counts(backend):
     if backend == "ref":
         assert delta["stream.upload_bytes"] == 0
         assert delta["stream.fetch_bytes"] == 0
+        assert delta.get("stream.compact_batches", 0) == 0
+        assert delta.get("stream.compact_slots", 0) == 0
         return
     x_pad, mu_pad = pad_for_screen(
         X, column_moments(X, chunk=cfg.chunk).mu, block_n=cfg.chunk, block_p=t
@@ -128,9 +133,22 @@ def test_screen_counters_match_shapes_and_counts(backend):
     pairs = len(computed)
     batches = -(-pairs // cfg.pair_batch)
     upload = batches * (x_pad.size * 4 + mu_pad.size * 4 + 4) + pairs * 2 * 4
-    fetch = pairs * (t * t * 4 + 4 + 2 * 4)  # tiles, counts, stats
+    # the kernel's entries above lam per pair, both orientations on the
+    # diagonal; each batch with any is compacted into _capacity(sum) slots
+    per_pair = [
+        int(edge[i * t:(i + 1) * t, j * t:(j + 1) * t].sum())
+        for (i, j), rec in sc.tiles.items() if not rec.skipped
+    ]
+    nnz = [sum(per_pair[b:b + cfg.pair_batch]) for b in range(0, pairs, cfg.pair_batch)]
+    slots = [_capacity(k) for k in nnz if k]
+    # counts and stats of every pair, then (3 + 1) * 4 B per slot and the
+    # 4-B length of each compacted batch
+    fetch = pairs * (4 + 2 * 4) + sum(16 * c + 4 for c in slots)
+    assert slots and len(slots) <= batches
     assert delta["stream.upload_bytes"] == upload
     assert delta["stream.fetch_bytes"] == fetch
+    assert delta["stream.compact_batches"] == len(slots)
+    assert delta["stream.compact_slots"] == sum(slots)
 
 
 def test_every_span_is_a_profiler_host_event(data, tmp_path):

@@ -111,6 +111,17 @@ def test_covgram_screen_compiles(on_tpu, n, p, block_p):
     )
 
 
+@pytest.mark.parametrize("npairs,capacity", [(64, 256), (24, 4096), (64, 2**20)])
+def test_compact_tiles_compiles(on_tpu, npairs, capacity):
+    """The device compaction of a kernel batch (plain XLA, no kernel)."""
+    from repro.kernels.covgram_screen.ops import compact_tiles
+
+    sds = jax.ShapeDtypeStruct((npairs, 512, 512), F32, sharding=on_tpu)
+    fn = functools.partial(compact_tiles, capacity=capacity)
+    text = jax.jit(fn).lower(sds).compile().as_text()
+    assert "scatter" not in text
+
+
 @pytest.mark.parametrize("p", [2400, 2560, 40])
 def test_threshold_cc_compiles(on_tpu, p):
     from repro.kernels.threshold_cc.ops import connected_components_kernel
