@@ -1,9 +1,15 @@
 """CPU rehearsal of the harness: every cell end to end at tiny size, the
-refusal without a chip, and a cell added as files alone."""
+refusal without a chip, and a cell added as files alone.
+
+A cell's CPU size is a file of its own, ``bench/tiny/<cell>.json``:
+``{"config": {...}, "workload": {...}}``, each key a dotted path into the
+cell's configuration or workload whose value replaces what stands there
+(nothing is merged)."""
 
 import copy
 import json
 import os
+import re
 import shutil
 import subprocess
 import sys
@@ -21,29 +27,33 @@ CANDIDATES = [
     w["name"] for w in json.loads((ROOT / "bench" / "candidates.json").read_text())["workloads"]
 ]
 
-TINY_NETWORKS = {
-    "01_a": {"parcels": 12, "system": "A"},
-    "02_b": {"parcels": 8, "system": "A"},
-    "03_c": {"parcels": 10, "system": "B"},
-}
+
+def _replace(tree: dict, overrides: dict, where: str) -> dict:
+    """``tree`` with the value at each dotted path of ``overrides`` replaced;
+    a path that names nothing in ``tree`` is an error, not a new key."""
+    out = copy.deepcopy(tree)
+    for path, value in overrides.items():
+        *parents, last = path.split(".")
+        node = out
+        for key in parents:
+            node = node.get(key) if isinstance(node, dict) else None
+        if not isinstance(node, dict) or last not in node:
+            raise harness.BenchError(f"{where}: {path!r} names nothing there")
+        node[last] = value
+    return out
 
 
 def tiny(name: str, root: Path = ROOT) -> harness.Cell:
-    """The cell as ``root``'s BENCHMARK.json defines it, at a size the CPU
-    can run."""
+    """The cell as ``root``'s BENCHMARK.json defines it, at the size its
+    ``bench/tiny/<cell>.json`` gives for the CPU."""
     cell = harness.find_cell(name, root=root)
-    cfg = copy.deepcopy(cell.config)
-    wl = dict(cell.workload)
-    if wl["traffic"] == "path":
-        cfg["n_samples"], cfg["n_genes"] = 60, 700
-        cfg["assumed"]["module_sizes"] = [40, 20, 12, 6, 4]
-        wl["lambdas"] = [0.6, 0.45] if "sparse" in name else [0.45, 0.3]
-    else:
-        cfg["n_frames"], cfg["n_parcels"] = 200, 30
-        cfg["assumed"]["networks"] = TINY_NETWORKS
-        wl.update(lambdas=[0.6, 0.4, 0.2], rate=20.0, warmup_seconds=0.3, settle_seconds=60,
-                  trace_seconds=0.2)
-    cell.config, cell.workload = cfg, wl
+    path = Path(root) / "bench" / "tiny" / f"{name}.json"
+    preset = harness.load_json(path)
+    unknown = set(preset) - {"config", "workload"}
+    if unknown:
+        raise harness.BenchError(f"{path}: keys {sorted(unknown)}, not config or workload")
+    cell.config = _replace(cell.config, preset.get("config", {}), f"{path}, config")
+    cell.workload = _replace(cell.workload, preset.get("workload", {}), f"{path}, workload")
     return cell
 
 
@@ -80,9 +90,10 @@ def fake_reduce(path, *, t0, t1, spans=(), chips=1):
     }
 
 
-@pytest.mark.parametrize("name", CELLS + CANDIDATES)
-def test_cell_end_to_end_on_cpu(name, monkeypatch, bench_root):
-    cell = tiny(name, bench_root)
+def rehearse(cell: harness.Cell, monkeypatch) -> None:
+    """One untraced and one traced run of ``cell`` on the CPU, the trace's
+    profiler and reduction stood in for: both correct, each with the
+    metrics the cell reports."""
     res = harness.run_cell(
         cell, seed=2**31 + 11, seconds=0.5, trace=False,
         devices=jax.devices(), t_start=time.perf_counter(),
@@ -108,6 +119,44 @@ def test_cell_end_to_end_on_cpu(name, monkeypatch, bench_root):
     assert res["breakdown"]["idle_gaps"]
 
 
+@pytest.mark.parametrize("name", CELLS + CANDIDATES)
+def test_cell_end_to_end_on_cpu(name, monkeypatch, bench_root):
+    rehearse(tiny(name, bench_root), monkeypatch)
+
+
+@pytest.mark.parametrize("name", CELLS + CANDIDATES)
+def test_every_cell_has_its_tiny_file(name, bench_root):
+    tiny(name, bench_root)  # a missing file raises, naming it
+
+
+def _checkout(tmp_path: Path) -> Path:
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path)
+    shutil.copytree(ROOT / "bench", tmp_path / "bench", ignore=shutil.ignore_patterns("__pycache__"))
+    return tmp_path
+
+
+@pytest.mark.parametrize("preset, named", [
+    ({"config": {"n_sample": 60}}, "'n_sample'"),
+    ({"config": {"assumed.module_size": [4]}}, "'assumed.module_size'"),
+    ({"config": {"n_samples.rows": 60}}, "'n_samples.rows'"),
+    ({"workload": {"lambda": [0.5]}}, "'lambda'"),
+    ({"configs": {"n_samples": 60}}, "'configs'"),
+], ids=["config_key", "nested_key", "below_a_leaf", "workload_key", "top_level_key"])
+def test_tiny_key_that_names_nothing_is_refused(preset, named, tmp_path):
+    root = _checkout(tmp_path)
+    (root / "bench" / "tiny" / f"{CELLS[0]}.json").write_text(json.dumps(preset))
+    with pytest.raises(harness.BenchError, match=named):
+        tiny(CELLS[0], root)
+
+
+def test_cell_without_tiny_file_is_refused(tmp_path):
+    root = _checkout(tmp_path)
+    path = root / "bench" / "tiny" / f"{CELLS[0]}.json"
+    path.unlink()
+    with pytest.raises(harness.BenchError, match=re.escape(str(path))):
+        tiny(CELLS[0], root)
+
+
 def _run(args, cwd, **env):
     return subprocess.run(
         [sys.executable, "bench/run.py", *args], cwd=cwd, capture_output=True,
@@ -126,9 +175,8 @@ def test_run_refuses_without_a_chip():
 
 
 def test_run_refuses_without_the_program(tmp_path):
-    shutil.copy(ROOT / "BENCHMARK.json", tmp_path)
-    shutil.copytree(ROOT / "bench", tmp_path / "bench", ignore=shutil.ignore_patterns("__pycache__"))
-    out = _run(["--workload", CELLS[0], "--seed", "1", "--seconds", "1", "--trace", "0"], tmp_path)
+    out = _run(["--workload", CELLS[0], "--seed", "1", "--seconds", "1", "--trace", "0"],
+               _checkout(tmp_path))
     assert out.returncode != 0
     assert "{" not in out.stdout
 
@@ -191,6 +239,118 @@ def test_cell_added_as_files_is_found(tmp_path):
     # every cell already in the benchmark is still found as before
     for name in CELLS:
         assert harness.find_cell(name, root=tmp_path).name == name
+
+
+TOY_SOLVE = '''
+"""Traffic ``toy_solve``: square roots by Newton's method in NumPy, work
+that goes through no part of the program."""
+
+import time
+
+import numpy as np
+
+from bench.harness import Window
+
+
+def step(x, a):
+    return 0.5 * (x + a / x)
+
+
+def answer(x):
+    return x
+
+
+class Driver:
+    def __init__(self, cell, seed, seconds, control=None):
+        rng = np.random.default_rng(seed)
+        self.a = rng.uniform(1.0, 4.0, int(cell.config["size"]))
+        self.sweeps = int(cell.workload["sweeps"])
+    def warmup(self):
+        pass
+    def run(self, seconds, profiler=None):
+        if profiler is not None:
+            profiler.start()
+        t0 = time.perf_counter()
+        x = np.ones_like(self.a)
+        for _ in range(self.sweeps):
+            x = step(x, self.a)
+        self.out = answer(x)
+        t1 = time.perf_counter()
+        if profiler is not None:
+            profiler.stop()
+        return Window(end_to_end={"lambda_s": (t1 - t0) / self.a.size}, attempted=self.a.size,
+                      failed=0, ctx={"units": self.a.size, "sweeps": self.sweeps,
+                                     "spans": [("toy.solve", t0, t1)]})
+    def close(self):
+        pass
+    def check(self, window):
+        return {"wrong": int(np.abs(self.out - np.sqrt(self.a)).max() > 1e-12)}
+'''
+
+TOY_SOLVE_FAULTS = '''
+def solver_state_unchanged(monkeypatch, cell):
+    monkeypatch.setattr(cell.traffic, "step", lambda x, a: x)
+    return "wrong"
+
+
+def answer_altered(monkeypatch, cell):
+    monkeypatch.setattr(cell.traffic, "answer", lambda x: x * (1.0 + 1e-3))
+    return "wrong"
+
+
+FAULTS = {"solver_state_unchanged": solver_state_unchanged, "answer_altered": answer_altered}
+'''
+
+
+def test_cell_of_a_new_kind_is_rehearsed_and_faulted(tmp_path, monkeypatch):
+    """A cell whose work bypasses the BCD and the engine's answer, added as
+    new files, entries appended to BENCHMARK.json and its name appended to
+    ``lambda_s``'s workloads: the generic rehearsal and the generic fault
+    test take it as they find it."""
+    from bench.test_bench_faults import faults_of, run_with_fault
+
+    root = _checkout(tmp_path)
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["configs"].append({
+        "name": "toy", "source": "a test", "file": "bench/configs/toy.json",
+        "reduced": [], "why": "a test"})
+    bench["workloads"].append({
+        "name": "toy.solve", "config": "toy", "traffic": "toy_solve", "chips": 1, "why": "a test"})
+    next(m for m in bench["end_to_end"] if m["name"] == "lambda_s")["workloads"].append("toy.solve")
+    bench["per_layer"].append({
+        "name": "toy_sweeps.solve", "unit": "count", "better": "lower", "source": "program_counter",
+        "layer": "execute", "moves": "lambda_s", "workloads": ["toy.solve"]})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    files = {
+        "configs/toy.json": '{"size": 100000}',
+        "workloads/toy.solve.json":
+            '{"config": "toy", "traffic": "toy_solve", "sweeps": 40, "limits": {"wrong": 0}}',
+        "traffic/toy_solve.py": TOY_SOLVE,
+        "metrics/toy_sweeps.solve.py": "def read(ctx):\n    return float(ctx['sweeps'])\n",
+        "tiny/toy.solve.json": '{"config": {"size": 50}, "workload": {"sweeps": 30}}',
+        "faults/toy_solve.py": TOY_SOLVE_FAULTS,
+    }
+    for rel, text in files.items():
+        assert not (root / "bench" / rel).exists()
+        (root / "bench" / rel).write_text(text)
+
+    cell = tiny("toy.solve", root)
+    assert cell.config["size"] == 50 and cell.workload["sweeps"] == 30
+    assert sorted(m["name"] for m in cell.end_to_end) == ["lambda_s", "setup_s"]
+    with monkeypatch.context() as mp:
+        rehearse(cell, mp)
+    faults = faults_of("toy_solve", root)
+    assert sorted(faults) == ["answer_altered", "solver_state_unchanged"]
+    for fault in faults:
+        with monkeypatch.context() as mp:
+            run_with_fault(tiny("toy.solve", root), fault, mp)
+
+    # the cells already in the benchmark report what they reported before
+    for name in CELLS:
+        before, after = harness.find_cell(name), harness.find_cell(name, root=root)
+        for kind in ("end_to_end", "per_layer"):
+            assert [m["name"] for m in getattr(after, kind)] == [
+                m["name"] for m in getattr(before, kind)]
 
 
 def test_unknown_cell_is_refused():
